@@ -23,7 +23,6 @@ accumulates in VMEM.
 import jax
 import jax.numpy as jnp
 import numpy as np
-import repro.compat  # noqa: F401  jax version shims
 from jax.sharding import AxisType, PartitionSpec as P
 
 from benchmarks.common import emit, timeit

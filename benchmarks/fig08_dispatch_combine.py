@@ -9,7 +9,6 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 import numpy as np
-import repro.compat  # noqa: F401  jax version shims
 from jax.sharding import AxisType, PartitionSpec as P
 
 from benchmarks.common import emit, timeit

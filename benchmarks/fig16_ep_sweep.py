@@ -140,7 +140,6 @@ def skew_sweep(alphas):
 
 def ep_degree_sweep():
     import jax
-    import repro.compat  # noqa: F401  jax version shims
     from jax.sharding import AxisType
 
     from benchmarks.fig08_dispatch_combine import build

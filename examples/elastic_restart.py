@@ -10,7 +10,6 @@ os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=8")
 
 import tempfile
 
-import repro.compat  # noqa: F401  jax version shims
 import jax
 
 from repro.checkpoint import Checkpointer

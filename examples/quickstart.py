@@ -13,7 +13,6 @@ os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=8")
 import jax
 import jax.numpy as jnp
 import numpy as np
-import repro.compat  # noqa: F401  jax version shims
 from jax.sharding import AxisType, PartitionSpec as P
 
 from repro.core.ep import (EPSpec, dispatch_combine_ht, dispatch_combine_ll,

@@ -27,10 +27,12 @@ PYTHONPATH=src${PYTHONPATH:+:$PYTHONPATH} python -m repro.analysis.lint src/repr
 PYTHONPATH=src${PYTHONPATH:+:$PYTHONPATH} python -m pytest -x -q \
     --durations=20 "$@"
 
-# Bounded interpret-mode step: execute the Pallas kernel bodies (not just
-# the jnp refs) through the ops-level mode dispatch on every run.
-REPRO_KERNEL_MODE=interpret PYTHONPATH=src${PYTHONPATH:+:$PYTHONPATH} \
-    python -m pytest -x -q tests/test_kernel_modes.py
+# Interpret-mode step: the Pallas kernel bodies (not just the jnp refs) at
+# every shape, the slow-marked ones included.  Each test passes the mode
+# as an argument (mode="interpret" / interpret=True); without one the
+# kernel mode follows the platform (Pallas on TPU, refs elsewhere).
+PYTHONPATH=src${PYTHONPATH:+:$PYTHONPATH} \
+    python -m pytest -x -q -m "" tests/test_kernel_modes.py tests/test_kernels.py
 
 # Static-analysis gate (DESIGN.md §17): the protocol verifier over
 # fig08-shaped one-shot plans and the fig14-shaped persistent-session slot

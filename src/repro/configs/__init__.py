@@ -7,11 +7,13 @@ from repro.configs.base import (
     ShapeCell,
     all_configs,
     cells_for,
+    cut_config,
     get_config,
     reduced_config,
 )
 
 __all__ = [
     "ARCH_IDS", "SHAPES", "MambaConfig", "ModelConfig", "MoEConfig",
-    "ShapeCell", "all_configs", "cells_for", "get_config", "reduced_config",
+    "ShapeCell", "all_configs", "cells_for", "cut_config", "get_config",
+    "reduced_config",
 ]

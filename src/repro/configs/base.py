@@ -242,6 +242,19 @@ def reduced_config(cfg: ModelConfig, *, n_layers: int = 2, d_model: int = 64,
         frontend_prefix=min(cfg.frontend_prefix, 4))
 
 
+def cut_config(cfg: ModelConfig, *, n_layers: Optional[int] = None,
+               vocab: Optional[int] = None) -> ModelConfig:
+    """The published config cut in depth and vocabulary only: every width
+    (d_model, heads, head_dim, experts, top-k, d_expert) stays as
+    published.  None keeps the published value."""
+    cuts = {}
+    if n_layers is not None:
+        cuts["n_layers"] = n_layers
+    if vocab is not None:
+        cuts["vocab_size"] = vocab
+    return dataclasses.replace(cfg, **cuts)
+
+
 def cells_for(cfg: ModelConfig) -> list[str]:
     """Shape cells this arch runs (long_500k only for sub-quadratic archs)."""
     out = []

@@ -10,7 +10,6 @@ import math
 from functools import partial
 from typing import Optional
 
-import repro.compat  # noqa: F401  jax version shims (jax.shard_map)
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
@@ -88,6 +87,18 @@ def make_ep_spec(cfg: ModelConfig, dist: DistCtx, *, mode: str,
                   wire_dtype=getattr(cfg.moe, "wire_dtype", "fp32"))
 
 
+def moe_path(dist: Optional[DistCtx], mode: str, ep_backend) -> str:
+    """Which compute path :func:`moe_apply` takes: ``"host"`` (a host
+    transport backend, outside jit), ``"dense"`` (the :func:`moe_ref`
+    oracle: no EP mesh, or ``mode="ref"``) or ``"ep"`` (the shard_map
+    dispatch/combine island with the grouped expert kernels)."""
+    if not ep_backend.jit_compatible and mode != "ref":
+        return "host"
+    if dist is None or not dist.ep_axes or mode == "ref":
+        return "dense"
+    return "ep"
+
+
 def moe_apply(cfg: ModelConfig, dist: Optional[DistCtx], p: dict, x: Array,
               *, mode: str = "ht", chunks: int = 1,
               backend=None) -> tuple[Array, dict]:
@@ -110,9 +121,10 @@ def moe_apply(cfg: ModelConfig, dist: Optional[DistCtx], p: dict, x: Array,
     be = backend if backend is not None else mcfg.ep_backend
     ep_be = get_backend(be) if isinstance(be, str) else be
 
-    if not ep_be.jit_compatible and mode != "ref":
+    path = moe_path(dist, mode, ep_be)
+    if path == "host":
         y, aux = _moe_host_sim(cfg, dist, rparams, p, x, mode, ep_be)
-    elif dist is None or not dist.ep_axes or mode == "ref":
+    elif path == "dense":
         t = x.reshape(-1, D)
         rout = route(mcfg, rparams, t, mcfg.n_experts)
         y = moe_ref(t, rout.top_idx, rout.top_w, p["w_gate"], p["w_up"],

@@ -15,7 +15,6 @@ from __future__ import annotations
 
 from functools import partial
 
-import repro.compat  # noqa: F401  jax version shims (jax.shard_map)
 import jax
 import jax.numpy as jnp
 from jax import lax

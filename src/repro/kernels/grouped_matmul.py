@@ -33,7 +33,7 @@ Three entry points:
   send buffer and no ``(E*C, D)`` expert-output intermediate ever touch HBM.
 
 ``grouped_swiglu_db`` is the double-buffered variant: token blocks stay in
-HBM (``pltpu.ANY``) and are DMA'd manually through two VMEM slots, so
+HBM (``pl.ANY``) and are DMA'd manually through two VMEM slots, so
 skipped (unoccupied) row-blocks skip their HBM traffic too — the BlockSpec
 pipeline cannot elide fetches for ``pl.when``-skipped steps, manual DMA can.
 
@@ -51,10 +51,28 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 
-def _dim_sem(n: int):
-    """Grid annotation: groups are parallel, row/col/reduce dims arbitrary."""
-    return pltpu.TPUCompilerParams(
-        dimension_semantics=("parallel",) + ("arbitrary",) * (n - 1))
+def _dim_sem(n: int, vmem_need: int | None = None):
+    """Grid annotation: groups are parallel, row/col/reduce dims arbitrary;
+    with ``vmem_need`` bytes, a scoped-VMEM limit to fit them."""
+    return pltpu.CompilerParams(
+        dimension_semantics=("parallel",) + ("arbitrary",) * (n - 1),
+        vmem_limit_bytes=(None if vmem_need is None
+                          else _vmem_limit(vmem_need)))
+
+
+def _vmem_limit(need: int) -> int:
+    """Scoped-VMEM limit for a kernel whose pipeline buffers and scratch
+    take ``need`` bytes: a quarter more for the compiler's temporaries,
+    and never below 32 MiB (v5e has 128 MiB of VMEM; its default scoped
+    limit of 16 MiB is exceeded by f32 weight blocks at D=2048)."""
+    return max(32 << 20, need + need // 4)
+
+
+def _swiglu_vmem(bm: int, bf: int, D: int, x_bytes: int,
+                 w_bytes: int) -> int:
+    """Bytes of one grouped-SwiGLU grid step: double-buffered token, gate,
+    up, down and output blocks plus the f32 accumulator."""
+    return 2 * (2 * bm * D * x_bytes + 3 * D * bf * w_bytes) + bm * D * 4
 
 
 def _norm_counts(counts, n_groups: int, cap: int):
@@ -138,9 +156,11 @@ def grouped_matmul_pallas(x: jax.Array, w: jax.Array,
 def _swiglu_block(x, wg, wu, wd, f, bf: int, F: int):
     """One f-block SwiGLU partial: silu(x@wg)*(x@wu) @ wd, masking the
     (ragged F) hidden-dim padding of the edge block — statically elided
-    when bf divides F."""
-    g = jnp.dot(x, wg, preferred_element_type=jnp.float32)
-    u = jnp.dot(x, wu, preferred_element_type=jnp.float32)
+    when bf divides F.  f32 operands multiply at f32 precision (Mosaic's
+    default may round them to bf16); bf16 operands take one MXU pass."""
+    prec = jax.lax.Precision.HIGHEST if x.dtype == jnp.float32 else None
+    g = jnp.dot(x, wg, preferred_element_type=jnp.float32, precision=prec)
+    u = jnp.dot(x, wu, preferred_element_type=jnp.float32, precision=prec)
     h = (g * jax.nn.sigmoid(g) * u).astype(x.dtype)
     wdm = wd
     if F % bf != 0:
@@ -148,7 +168,7 @@ def _swiglu_block(x, wg, wu, wd, f, bf: int, F: int):
             + f * bf
         h = jnp.where(fcols < F, h, 0)
         wdm = jnp.where(fcols.reshape(-1, 1) < F, wd, 0)
-    return jnp.dot(h, wdm, preferred_element_type=jnp.float32)
+    return jnp.dot(h, wdm, preferred_element_type=jnp.float32, precision=prec)
 
 
 def _swiglu_kernel(cnt_ref, x_ref, wg_ref, wu_ref, wd_ref, o_ref, acc_ref, *,
@@ -219,7 +239,8 @@ def grouped_swiglu_pallas(x: jax.Array, w_gate: jax.Array, w_up: jax.Array,
             scratch_shapes=[pltpu.VMEM((bm, D), jnp.float32)],
         ),
         out_shape=jax.ShapeDtypeStruct((G, C, D), x.dtype),
-        compiler_params=_dim_sem(3),
+        compiler_params=_dim_sem(3, _swiglu_vmem(
+            bm, bf, D, x.dtype.itemsize, w_gate.dtype.itemsize)),
         interpret=interpret,
     )(cnt, x, w_gate, w_up, w_down)
     return out.reshape(E, B * C, D) if B > 1 else out
@@ -296,7 +317,7 @@ def grouped_swiglu_db_pallas(x: jax.Array, w_gate: jax.Array, w_up: jax.Array,
             num_scalar_prefetch=1,
             grid=(E, nm, nf),
             in_specs=[
-                pl.BlockSpec(memory_space=pltpu.ANY),
+                pl.BlockSpec(memory_space=pl.ANY),
                 pl.BlockSpec((1, D, bf), lambda g, i, f, c: (g, 0, f)),
                 pl.BlockSpec((1, D, bf), lambda g, i, f, c: (g, 0, f)),
                 pl.BlockSpec((1, bf, D), lambda g, i, f, c: (g, f, 0)),
@@ -307,7 +328,8 @@ def grouped_swiglu_db_pallas(x: jax.Array, w_gate: jax.Array, w_up: jax.Array,
                             pltpu.SemaphoreType.DMA((2,))],
         ),
         out_shape=jax.ShapeDtypeStruct((E, C, D), x.dtype),
-        compiler_params=_dim_sem(3),
+        compiler_params=_dim_sem(3, _swiglu_vmem(
+            bm, bf, D, x.dtype.itemsize, w_gate.dtype.itemsize)),
         interpret=interpret,
     )(cnt, x, w_gate, w_up, w_down)
 
@@ -315,7 +337,7 @@ def grouped_swiglu_db_pallas(x: jax.Array, w_gate: jax.Array, w_up: jax.Array,
 # ================================== fused gather -> swiglu -> scatter =====
 def _gss_kernel(src_ref, cnt_ref, x_ref, ws_ref, wg_ref, wu_ref, wd_ref,
                 o_ref, xs_ref, acc_ref, oacc_ref, *, bm: int, bf: int,
-                C: int, F: int, nf: int):
+                C: int, F: int, nf: int, x_dtype):
     e, i, f = pl.program_id(0), pl.program_id(1), pl.program_id(2)
     ne, nm = pl.num_programs(0), pl.num_programs(1)
     n_slots = ne * C
@@ -341,27 +363,38 @@ def _gss_kernel(src_ref, cnt_ref, x_ref, ws_ref, wg_ref, wu_ref, wd_ref,
     @pl.when(occ)
     def _():
         rows = jax.lax.broadcasted_iota(jnp.int32, (bm, 1), 0) + i * bm
-        xm = jnp.where(rows < cnt, xs_ref[...], 0)
+        xm = jnp.where(rows < cnt, xs_ref[...], 0).astype(x_dtype)
         acc_ref[...] += _swiglu_block(xm, wg_ref[0], wu_ref[0], wd_ref[0],
                                       f, bf, F)
 
     # weighted fp32 scatter-add into the persistent per-token accumulator
     @pl.when(occ & (f == nf - 1))
     def _():
-        y = acc_ref[...] * ws_ref[0, :].astype(jnp.float32)[:, None]
+        # weight the block in place; rows are then read back by ref slice
+        # (Mosaic has no dynamic_slice of a value)
+        acc_ref[...] = acc_ref[...] * ws_ref[0, 0, :].astype(
+            jnp.float32)[:, None]
 
         def scatter(r, _):
             @pl.when(i * bm + r < cnt)
             def _():
                 s = src_ref[jnp.minimum(e * C + i * bm + r, n_slots - 1)]
-                oacc_ref[pl.ds(s, 1), :] += jax.lax.dynamic_slice(
-                    y, (r, 0), (1, y.shape[1]))
+                oacc_ref[pl.ds(s, 1), :] += acc_ref[pl.ds(r, 1), :]
             return 0
         jax.lax.fori_loop(0, bm, scatter, 0)
 
     @pl.when((e == ne - 1) & (i == nm - 1) & (f == nf - 1))
     def _():
         o_ref[...] = oacc_ref[...]
+
+
+def _gss_vmem(Tp1: int, D: int, bm: int, bf: int, w_bytes: int) -> int:
+    """Bytes of the fused kernel's working set: the f32 token table and
+    f32 output block (two pipeline buffers each) and the f32 accumulator,
+    all (T+1, D); double-buffered gate/up/down weight and slot-weight
+    blocks; the two (bm, D) f32 scratch blocks."""
+    return (Tp1 * D * 4 * 5 + 2 * 3 * D * bf * w_bytes
+            + 2 * bm * 4 + 2 * bm * D * 4)
 
 
 @functools.partial(jax.jit, static_argnames=("bm", "bf", "interpret"))
@@ -380,10 +413,12 @@ def gather_swiglu_scatter_pallas(x_ext: jax.Array, src_of_slot: jax.Array,
     w_slot: (E*C,) combine weights (0 for empty slots); counts: (E,)
     occupied prefix per expert bucket.  Returns (T, D) float32 partials.
 
-    The (T+1, D) token table and fp32 accumulator are VMEM-resident, which
-    bounds T: callers should fall back to gather -> grouped_swiglu ->
-    scatter (the unfused composition, same math) when they do not fit —
-    see ``kernels.ops.gather_swiglu_scatter``.
+    The (T+1, D) token table (gathered in fp32: Mosaic loads single rows
+    of 32-bit data only, then casts each block back to ``x_ext.dtype``)
+    and the fp32 accumulator are VMEM-resident, which bounds T: callers
+    should fall back to gather -> grouped_swiglu -> scatter (the unfused
+    composition, same math) when they do not fit — see
+    ``kernels.ops.gather_swiglu_scatter``.
     """
     Tp1, D = x_ext.shape
     E, _, F = w_gate.shape
@@ -394,24 +429,26 @@ def gather_swiglu_scatter_pallas(x_ext: jax.Array, src_of_slot: jax.Array,
     assert B == 1, "fused kernel takes flat per-expert counts"
     bm, bf = min(bm, C), min(bf, F)
     nm, nf = pl.cdiv(C, bm), pl.cdiv(F, bf)
-    # pad the per-slot weights to whole row-blocks so the (1, bm) weight
-    # block of the ragged edge never reads past C
-    ws = jnp.zeros((E, nm * bm), jnp.float32).at[:, :C].set(
+    # pad the per-slot weights to whole row-blocks so the (1, 1, bm) weight
+    # block of the ragged edge never reads past C; the unit middle dim
+    # keeps that block on the TPU's (8, 128) tiling rule
+    ws = jnp.zeros((E, 1, nm * bm), jnp.float32).at[:, 0, :C].set(
         jnp.asarray(w_slot, jnp.float32).reshape(E, C))
     out = pl.pallas_call(
-        functools.partial(_gss_kernel, bm=bm, bf=bf, C=C, F=F, nf=nf),
+        functools.partial(_gss_kernel, bm=bm, bf=bf, C=C, F=F, nf=nf,
+                          x_dtype=x_ext.dtype),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(E, nm, nf),
             in_specs=[
                 pl.BlockSpec((Tp1, D), lambda e, i, f, s, c: (0, 0)),
-                pl.BlockSpec((1, bm), lambda e, i, f, s, c: (e, i)),
+                pl.BlockSpec((1, 1, bm), lambda e, i, f, s, c: (e, 0, i)),
                 pl.BlockSpec((1, D, bf), lambda e, i, f, s, c: (e, 0, f)),
                 pl.BlockSpec((1, D, bf), lambda e, i, f, s, c: (e, 0, f)),
                 pl.BlockSpec((1, bf, D), lambda e, i, f, s, c: (e, f, 0)),
             ],
             out_specs=pl.BlockSpec((Tp1, D), lambda e, i, f, s, c: (0, 0)),
-            scratch_shapes=[pltpu.VMEM((bm, D), x_ext.dtype),
+            scratch_shapes=[pltpu.VMEM((bm, D), jnp.float32),
                             pltpu.VMEM((bm, D), jnp.float32),
                             pltpu.VMEM((Tp1, D), jnp.float32)],
         ),
@@ -419,9 +456,12 @@ def gather_swiglu_scatter_pallas(x_ext: jax.Array, src_of_slot: jax.Array,
         # every grid dim is 'arbitrary': the per-token accumulator crosses
         # the expert dim (zero-init at the first step, flush at the last),
         # so a Megacore-parallel split of it would shear the accumulation
-        compiler_params=pltpu.TPUCompilerParams(
-            dimension_semantics=("arbitrary",) * 3),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",) * 3,
+            vmem_limit_bytes=_vmem_limit(_gss_vmem(Tp1, D, bm, bf,
+                                                   w_gate.dtype.itemsize))),
         interpret=interpret,
-    )(jnp.asarray(src_of_slot, jnp.int32), cnt, x_ext, ws,
+    )(jnp.asarray(src_of_slot, jnp.int32), cnt, x_ext.astype(jnp.float32),
+      ws,
       w_gate, w_up, w_down)
     return out[:-1]

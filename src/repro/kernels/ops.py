@@ -1,25 +1,26 @@
 """Jitted public wrappers around the Pallas kernels with mode dispatch.
 
-Modes (set ``repro.kernels.ops.KERNEL_MODE`` or env ``REPRO_KERNEL_MODE``):
-- "ref":       pure-jnp oracle (default on CPU; what the dry-run lowers)
+Modes (the ``mode=`` argument; None means :func:`platform_mode`):
+- "ref":       pure-jnp oracle (the default off the TPU)
 - "interpret": pl.pallas_call(interpret=True) — CPU validation of kernel code
-- "pallas":    compiled Pallas kernel (TPU target)
+- "pallas":    compiled Pallas kernel (the default on the TPU)
 """
 from __future__ import annotations
 
-import os
-from functools import partial
-
 import jax
-import jax.numpy as jnp
 
 from repro.kernels import ref as _ref
 
-KERNEL_MODE = os.environ.get("REPRO_KERNEL_MODE", "ref")
+
+def platform_mode() -> str:
+    """Compiled Pallas kernels on a TPU backend, the jnp references
+    elsewhere.  There is no fallback: on the TPU a kernel that does not
+    compile fails the program."""
+    return "pallas" if jax.default_backend() == "tpu" else "ref"
 
 
 def _mode(override: str | None = None) -> str:
-    return override or KERNEL_MODE
+    return override or platform_mode()
 
 
 def grouped_matmul(x, w, counts=None, *, mode: str | None = None):
@@ -41,29 +42,20 @@ def grouped_swiglu(x, w_gate, w_up, w_down, counts=None, *,
     swiglu(0) == 0, the jnp ref then skips the occupancy mask — it would
     be pure overhead on XLA — while the kernel paths still use counts to
     skip the padding's flops.
-
-    ``REPRO_SWIGLU_DB=1`` selects the double-buffered variant (manual
-    HBM->VMEM token DMA: occupancy-skipped blocks skip their HBM reads,
-    which the BlockSpec pipeline cannot do); flat counts only.
     """
     m = _mode(mode)
     if m == "ref":
         return _ref.grouped_swiglu_ref(x, w_gate, w_up, w_down,
                                        counts=None if zero_padded else counts)
-    flat = counts is None or getattr(counts, "ndim", 1) == 1
-    if os.environ.get("REPRO_SWIGLU_DB") == "1" and flat:
-        from repro.kernels.grouped_matmul import grouped_swiglu_db_pallas
-        return grouped_swiglu_db_pallas(x, w_gate, w_up, w_down, counts,
-                                        interpret=(m == "interpret"))
     from repro.kernels.grouped_matmul import grouped_swiglu_pallas
     return grouped_swiglu_pallas(x, w_gate, w_up, w_down, counts,
                                  interpret=(m == "interpret"))
 
 
 # VMEM budget for the fused kernel's (T+1, D)-sized resident buffers: the
-# token table (input dtype) + the fp32 accumulator scratch + the fp32
-# output block, all live simultaneously (see gather_swiglu_scatter_pallas);
-# above this the unfused composition is used — same math, one materialized
+# fp32 token table + the fp32 accumulator scratch + the fp32 output block,
+# all live simultaneously (see gather_swiglu_scatter_pallas); above this
+# the unfused composition is used — same math, one materialized
 # intermediate.
 GSS_VMEM_BYTES = 8 * 1024 * 1024
 
@@ -80,7 +72,7 @@ def gather_swiglu_scatter(x_ext, src_of_slot, w_slot, w_gate, w_up, w_down,
     occupancy mask."""
     m = _mode(mode)
     Tp1, D = x_ext.shape
-    resident = Tp1 * D * (x_ext.dtype.itemsize + 4 + 4)
+    resident = Tp1 * D * (4 + 4 + 4)
     if m != "ref" and resident <= GSS_VMEM_BYTES:
         from repro.kernels.grouped_matmul import gather_swiglu_scatter_pallas
         return gather_swiglu_scatter_pallas(

@@ -61,6 +61,18 @@ def gather_quantize_ref(x_ext, src_of_slot, counts=None, *,
 
 
 # ----------------------------------------------------------------- kernel --
+def _round_to_f16(y):
+    """Round f32 to the nearest-even f16 value, kept in f32: the codec's
+    f32 -> f16 step without an f16 type, which Mosaic cannot lower on
+    every TPU generation.  Exact for |y| <= 448 (the clipped fp8 range);
+    below f16's normal range it keeps 10 mantissa bits, and every such
+    value still rounds to zero in the fp8 step that follows."""
+    b = jax.lax.bitcast_convert_type(y, jnp.uint32)
+    lsb = (b >> 13) & 1
+    b = (b + 0x0FFF + lsb) & ~jnp.uint32(0x1FFF)
+    return jax.lax.bitcast_convert_type(b, jnp.float32)
+
+
 def _gq_kernel(src_ref, cnt_ref, x_ref, q_ref, s_ref, xs_ref, *, bm: int,
                C: int, d: int, nb: int, qmax: float, qinv: float, f8: bool):
     e, i = pl.program_id(0), pl.program_id(1)
@@ -87,7 +99,7 @@ def _gq_kernel(src_ref, cnt_ref, x_ref, q_ref, s_ref, xs_ref, *, bm: int,
             sg = jnp.where(scale == 0, 1.0, scale)
             y = jnp.clip(seg / sg, -qmax, qmax)
             if f8:   # wire rounding contract: f32 -> f16 -> f8e4m3 (codec)
-                qv = y.astype(jnp.float16).astype(jnp.float8_e4m3fn)
+                qv = _round_to_f16(y).astype(jnp.float8_e4m3fn)
             else:
                 qv = jnp.clip(jnp.round(y), -127, 127).astype(jnp.int8)
             q_ref[0, :, j * WIRE_BLOCK:min((j + 1) * WIRE_BLOCK, d)] = qv
@@ -143,7 +155,7 @@ def gather_quantize_pallas(x_ext: jax.Array, src_of_slot: jax.Array,
             jax.ShapeDtypeStruct((E, C, D), _qdtype(wire_dtype)),
             jax.ShapeDtypeStruct((E, C, nb), jnp.float32),
         ],
-        compiler_params=pltpu.TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(jnp.asarray(src_of_slot, jnp.int32), cnt, x_ext)
@@ -172,7 +184,7 @@ def dequantize_pallas(q: jax.Array, scales: jax.Array, *, bm: int = 256,
                   pl.BlockSpec((bm, nb), lambda i: (i, 0))],
         out_specs=pl.BlockSpec((bm, D), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((N, D), jnp.float32),
-        compiler_params=pltpu.TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )(q, scales)

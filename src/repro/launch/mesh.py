@@ -10,7 +10,7 @@ from __future__ import annotations
 
 import jax
 
-from repro.compat import AxisType  # jax version shims (make_mesh/AxisType)
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -20,10 +20,14 @@ def make_production_mesh(*, multi_pod: bool = False):
 
 
 def make_bench_mesh(n_devices: int, model: int = 4):
-    """Small CPU-device mesh for benchmarks/integration tests."""
-    data = n_devices // model
-    return jax.make_mesh((data, model), ("data", "model"),
-                         axis_types=(AxisType.Auto,) * 2)
+    """(data, model) mesh over the first ``n_devices`` local devices, for
+    benchmarks, integration tests and ``--mesh local`` launches."""
+    if model < 1 or n_devices % model:
+        raise ValueError(f"{n_devices} devices do not split into a model "
+                         f"axis of {model}")
+    return jax.make_mesh((n_devices // model, model), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2,
+                         devices=jax.devices()[:n_devices])
 
 
 # TPU v5e hardware constants for the roofline (assignment spec)

@@ -1,28 +1,140 @@
 """Serving launcher: batched prefill + decode loop with the LL EP mode.
 
-  PYTHONPATH=src python -m repro.launch.serve --arch qwen2_moe_a2_7b \
-      --reduced --batch 4 --prompt-len 32 --gen 16
+  PYTHONPATH=src python -m repro.launch.serve --arch moonshot_v1_16b_a3b \
+      --layers 2 --vocab 20480 --batch 4 --prompt-len 256 --gen 32
+
+Without ``--reduced``, ``--layers``/``--vocab`` cut only the depth and the
+vocabulary of the published config; its widths stay.  ``--reduced`` runs
+the tiny same-family config (widths cut too) used on the CPU.
 
 Prefill is ONE batched forward pass (``model_zoo.prefill``) that fills the
 KV cache for the whole prompt, then decode proceeds token-at-a-time in LL
 mode — the prefill/decode split the EP-native serving engine
-(``repro.serving``) schedules continuously.  ``--ep-backend``/``--wire-dtype``
-mirror ``launch/train.py``.
+(``repro.serving``) schedules continuously.  With ``--mesh local`` the
+parameters and the KV cache are placed with their mesh shardings and the
+prompt runs through the (sharded-cache) decode step.  ``--ep-backend``/
+``--wire-dtype`` mirror ``launch/train.py``.
 """
 from __future__ import annotations
 
 import argparse
 import dataclasses
 import time
+from functools import partial
+from typing import Optional
+
+import jax
+import jax.numpy as jnp
+from jax.sharding import NamedSharding
+
+from repro.configs import ModelConfig, cut_config, get_config, reduced_config
+from repro.distributed.sharding import (DistCtx, cache_pspecs, make_dist_ctx,
+                                        param_shardings)
+from repro.models import model_zoo as Z
+
+
+def build_config(arch: str, *, reduced: bool = False,
+                 layers: Optional[int] = None, d_model: int = 128,
+                 vocab: Optional[int] = None, ep_backend: str = "",
+                 wire_dtype: str = "") -> ModelConfig:
+    """The served config: ``reduced`` cuts widths too (CPU smoke size);
+    otherwise only depth and vocabulary are cut, where given."""
+    cfg = get_config(arch)
+    if reduced:
+        cfg = reduced_config(cfg, n_layers=layers or 2, d_model=d_model,
+                             vocab=vocab or 512)
+    else:
+        cfg = cut_config(cfg, n_layers=layers, vocab=vocab)
+    moe_over = {}
+    if ep_backend:
+        moe_over["ep_backend"] = ep_backend
+    if wire_dtype:
+        moe_over["wire_dtype"] = wire_dtype
+    if moe_over:
+        cfg = dataclasses.replace(
+            cfg, moe=dataclasses.replace(cfg.moe, **moe_over))
+    return cfg
+
+
+def init_params(cfg: ModelConfig, dist: Optional[DistCtx], key) -> dict:
+    """Random parameters from ``key``; on a mesh each leaf is created with
+    its ``param_shardings`` placement (experts split over the EP axes)."""
+    init = partial(Z.init_params, cfg)
+    if dist is None:
+        return jax.jit(init)(key)
+    shardings = param_shardings(cfg, dist, jax.eval_shape(init, key))
+    return jax.jit(init, out_shardings=shardings)(key)
+
+
+def new_cache(cfg: ModelConfig, dist: Optional[DistCtx], batch: int,
+              max_len: int) -> dict:
+    """An empty KV cache in the compute dtype; on a mesh placed by
+    ``cache_pspecs``."""
+    init = partial(Z.init_cache, cfg, batch, max_len, jnp.dtype(cfg.dtype))
+    if dist is None:
+        return jax.jit(init)()
+    specs = cache_pspecs(cfg, dist, jax.eval_shape(init), batch)
+    return jax.jit(init, out_shardings=jax.tree.map(
+        lambda s: NamedSharding(dist.mesh, s), specs))()
+
+
+def compile_steps(cfg: ModelConfig, dist: Optional[DistCtx], params: dict,
+                  cache: dict, prompts):
+    """Ahead-of-time compiled ``(prefill, step)`` for these shapes.
+    ``prefill`` is None where the prompt goes through the decode step (a
+    model-axis mesh shards the cache; mamba stacks)."""
+    step = jax.jit(partial(Z.decode_step, cfg, dist=dist, moe_mode="ll"),
+                   donate_argnums=(1,))
+    step = step.lower(params, cache, prompts[:, :1], jnp.int32(0)).compile()
+    prefill = None
+    if not cfg.mamba.enabled and (dist is None or dist.model_axis is None):
+        prefill = jax.jit(partial(Z.prefill, cfg, moe_mode="ht"),
+                          donate_argnums=(1,))
+        prefill = prefill.lower(params, cache, prompts).compile()
+    return prefill, step
+
+
+@partial(jax.jit, static_argnums=1)
+def _greedy(logits, vocab: int):
+    return jnp.argmax(logits[:, :vocab], axis=-1)[:, None].astype(jnp.int32)
+
+
+def generate(cfg: ModelConfig, prefill, step, params: dict, cache: dict,
+             prompts, gen: int):
+    """Greedy generation of ``gen`` tokens after ``prompts`` (B, S).
+
+    Returns ``(tokens (B, gen) int32, logits (B, gen, V_pad) f32)``: row
+    ``i`` of the logits is the distribution token ``i`` was drawn from.
+    ``cache`` is donated."""
+    S = prompts.shape[1]
+    if prefill is not None:
+        logits, cache = prefill(params, cache, prompts)
+    else:
+        for t in range(S - 1):
+            _, cache = step(params, cache, prompts[:, t:t + 1], jnp.int32(t))
+        logits, cache = step(params, cache, prompts[:, -1:], jnp.int32(S - 1))
+    logits_all = [logits]
+    tok = _greedy(logits, cfg.vocab_size)
+    tokens = [tok]
+    for t in range(S, S + gen - 1):
+        logits, cache = step(params, cache, tok, jnp.int32(t))
+        logits_all.append(logits)
+        tok = _greedy(logits, cfg.vocab_size)
+        tokens.append(tok)
+    return jnp.concatenate(tokens, axis=1), jnp.stack(logits_all, axis=1)
 
 
 def main(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--reduced", action="store_true")
-    ap.add_argument("--layers", type=int, default=2)
-    ap.add_argument("--d-model", type=int, default=128)
-    ap.add_argument("--vocab", type=int, default=512)
+    ap.add_argument("--layers", type=int, default=None,
+                    help="depth cut (default: published, or 2 if --reduced)")
+    ap.add_argument("--d-model", type=int, default=128,
+                    help="width of the --reduced config")
+    ap.add_argument("--vocab", type=int, default=None,
+                    help="vocabulary cut (default: published, or 512 if "
+                         "--reduced)")
     ap.add_argument("--batch", type=int, default=4)
     ap.add_argument("--prompt-len", type=int, default=32)
     ap.add_argument("--gen", type=int, default=16)
@@ -37,74 +149,36 @@ def main(argv=None):
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
 
-    import jax
-    import jax.numpy as jnp
-    from functools import partial
-    from repro.configs import get_config, reduced_config
-    from repro.distributed.sharding import make_dist_ctx
+    from repro.launch.compile_cache import enable_compile_cache
     from repro.launch.mesh import make_bench_mesh
-    from repro.models import model_zoo as Z
 
-    cfg = get_config(args.arch)
-    if args.reduced:
-        cfg = reduced_config(cfg, n_layers=args.layers, d_model=args.d_model,
-                             vocab=args.vocab)
-    moe_over = {}
-    if args.ep_backend:
-        moe_over["ep_backend"] = args.ep_backend
-    if args.wire_dtype:
-        moe_over["wire_dtype"] = args.wire_dtype
-    if moe_over:
-        cfg = dataclasses.replace(
-            cfg, moe=dataclasses.replace(cfg.moe, **moe_over))
+    enable_compile_cache()
+    cfg = build_config(args.arch, reduced=args.reduced, layers=args.layers,
+                       d_model=args.d_model, vocab=args.vocab,
+                       ep_backend=args.ep_backend,
+                       wire_dtype=args.wire_dtype)
     dist = None
     if args.mesh == "local":
         mesh = make_bench_mesh(len(jax.devices()), model=args.local_model_axis)
         dist = make_dist_ctx(cfg, mesh)
 
     key = jax.random.PRNGKey(args.seed)
-    params = Z.init_params(cfg, key)
-    B = args.batch
-    max_len = args.prompt_len + args.gen
-    cache = Z.init_cache(cfg, B, max_len)
+    params = init_params(cfg, dist, key)
+    B, max_len = args.batch, args.prompt_len + args.gen
     prompts = jax.random.randint(key, (B, args.prompt_len), 0, cfg.vocab_size)
-
-    step = jax.jit(partial(Z.decode_step, cfg, dist=dist, moe_mode="ll"),
-                   donate_argnums=(1,))
-    batched_prefill = (not cfg.mamba.enabled
-                       and (dist is None or dist.model_axis is None))
     t0 = time.perf_counter()
-    out_tokens = []
-    if batched_prefill:
-        # ONE forward pass fills cache[:, :prompt_len] and yields the
-        # first generated token from the last prompt position's logits
-        pre = jax.jit(partial(Z.prefill, cfg, moe_mode="ht"),
-                      donate_argnums=(1,))
-        logits, cache = pre(params, cache, prompts)
-        t_first = time.perf_counter() - t0
-        nxt = jnp.argmax(logits[:, :cfg.vocab_size], axis=-1)
-        tok = nxt[:, None].astype(jnp.int32)
-        out_tokens.append(tok)
-        t_start = args.prompt_len
-    else:
-        # sharded-cache / mamba fallback: prefill via decode steps
-        tok = prompts[:, :1]
-        for t in range(args.prompt_len - 1):
-            logits, cache = step(params, cache, tok, jnp.int32(t))
-            tok = prompts[:, t + 1:t + 2]
-        t_first = None
-        t_start = args.prompt_len - 1
-    for t in range(t_start, max_len - 1):
-        logits, cache = step(params, cache, tok, jnp.int32(t))
-        nxt = jnp.argmax(logits[:, :cfg.vocab_size], axis=-1)
-        tok = nxt[:, None].astype(jnp.int32)
-        out_tokens.append(tok)
+    prefill, step = compile_steps(cfg, dist, params,
+                                  new_cache(cfg, dist, B, max_len), prompts)
+    t_compile = time.perf_counter() - t0
+    t0 = time.perf_counter()
+    tokens, _ = generate(cfg, prefill, step, params,
+                         new_cache(cfg, dist, B, max_len), prompts, args.gen)
+    tokens = jax.block_until_ready(tokens)
     dt = time.perf_counter() - t0
-    total = B * len(out_tokens)
-    ttft = f", ttft {t_first * 1e3:.0f}ms" if t_first is not None else ""
-    print(f"[serve] generated {total} tokens in {dt:.2f}s "
-          f"({total / dt:.1f} tok/s{ttft}), first sequence: "
-          f"{[int(t[0, 0]) for t in out_tokens[:8]]}")
+    total = tokens.size
+    print(f"[serve] compiled in {t_compile:.2f}s; generated {total} tokens "
+          f"in {dt:.2f}s ({total / dt:.1f} tok/s), first sequence: "
+          f"{tokens[0, :8].tolist()}")
     return 0
 
 

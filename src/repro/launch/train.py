@@ -5,43 +5,14 @@
 
 ``--reduced`` runs the family-faithful small config on local devices (the
 CPU path used by examples/CI); the full config targets the production mesh.
+As in ``launch/serve.py``, ``--layers``/``--vocab`` without ``--reduced``
+cut only the depth and the vocabulary of the published config.
 """
 from __future__ import annotations
 
 import argparse
 import json
-import os
 from pathlib import Path
-
-# XLA latency-hiding / pipelined-collective preset (--xla-pipelining): the
-# collective-side analogue of the substrate's cross-layer comm/compute
-# overlap — async streams + pipelined all-gather/reduce-scatter/all-reduce
-# let XLA overlap EP collectives with non-MoE compute (MaxText's production
-# flag set).  Must land in XLA_FLAGS before jax is imported.
-XLA_PIPELINING_FLAGS = (
-    "--xla_gpu_enable_latency_hiding_scheduler=true",
-    "--xla_gpu_enable_triton_gemm=false",
-    "--xla_gpu_enable_highest_priority_async_stream=true",
-    "--xla_gpu_all_reduce_combine_threshold_bytes=134217728",
-    "--xla_gpu_all_gather_combine_threshold_bytes=134217728",
-    "--xla_gpu_reduce_scatter_combine_threshold_bytes=67108864",
-    "--xla_gpu_enable_pipelined_all_gather=true",
-    "--xla_gpu_enable_pipelined_reduce_scatter=true",
-    "--xla_gpu_enable_pipelined_all_reduce=true",
-    "--xla_gpu_enable_while_loop_double_buffering=true",
-    "--xla_gpu_enable_all_gather_combine_by_dim=false",
-    "--xla_gpu_enable_reduce_scatter_combine_by_dim=false",
-)
-
-
-def apply_xla_pipelining_flags(env=os.environ) -> str:
-    """Append the pipelining preset to XLA_FLAGS (idempotent); returns the
-    resulting value.  Call before the first ``import jax``."""
-    cur = env.get("XLA_FLAGS", "")
-    add = [f for f in XLA_PIPELINING_FLAGS if f not in cur]
-    val = " ".join(filter(None, [cur, *add]))
-    env["XLA_FLAGS"] = val
-    return val
 
 
 def main(argv=None):
@@ -51,9 +22,13 @@ def main(argv=None):
     ap.add_argument("--batch", type=int, default=8)
     ap.add_argument("--seq", type=int, default=256)
     ap.add_argument("--reduced", action="store_true")
-    ap.add_argument("--layers", type=int, default=2)
-    ap.add_argument("--d-model", type=int, default=128)
-    ap.add_argument("--vocab", type=int, default=512)
+    ap.add_argument("--layers", type=int, default=None,
+                    help="depth cut (default: published, or 2 if --reduced)")
+    ap.add_argument("--d-model", type=int, default=128,
+                    help="width of the --reduced config")
+    ap.add_argument("--vocab", type=int, default=None,
+                    help="vocabulary cut (default: published, or 512 if "
+                         "--reduced)")
     ap.add_argument("--moe-mode", default="ht", choices=["ht", "ll", "ref"])
     ap.add_argument("--moe-chunks", type=int, default=1)
     ap.add_argument("--ep-backend", default="",
@@ -62,9 +37,6 @@ def main(argv=None):
     ap.add_argument("--wire-dtype", default="",
                     choices=["", "fp32", "fp8", "int8"],
                     help="dispatch wire payload dtype (DESIGN §14)")
-    ap.add_argument("--xla-pipelining", action="store_true",
-                    help="enable the XLA latency-hiding/pipelined-collective "
-                         "flag preset (set before jax imports)")
     ap.add_argument("--lr", type=float, default=3e-4)
     ap.add_argument("--ckpt-dir", default="")
     ap.add_argument("--ckpt-every", type=int, default=50)
@@ -78,32 +50,21 @@ def main(argv=None):
     ap.add_argument("--history-out", default="")
     args = ap.parse_args(argv)
 
-    if args.xla_pipelining:
-        apply_xla_pipelining_flags()
-
-    import dataclasses
-
     import jax
     from repro.checkpoint import Checkpointer
-    from repro.configs import SHAPES, get_config, reduced_config
     from repro.data.pipeline import DataConfig, data_iterator
     from repro.distributed.fault import FailureInjector
     from repro.distributed.sharding import make_dist_ctx
+    from repro.launch.compile_cache import enable_compile_cache
     from repro.launch.mesh import make_bench_mesh, make_production_mesh
+    from repro.launch.serve import build_config
     from repro.training.train_loop import HParams, Watchdog, train_loop
 
-    cfg = get_config(args.arch)
-    if args.reduced:
-        cfg = reduced_config(cfg, n_layers=args.layers, d_model=args.d_model,
-                             vocab=args.vocab)
-    moe_over = {}
-    if args.ep_backend:
-        moe_over["ep_backend"] = args.ep_backend
-    if args.wire_dtype:
-        moe_over["wire_dtype"] = args.wire_dtype
-    if moe_over:
-        cfg = dataclasses.replace(
-            cfg, moe=dataclasses.replace(cfg.moe, **moe_over))
+    enable_compile_cache()
+    cfg = build_config(args.arch, reduced=args.reduced, layers=args.layers,
+                       d_model=args.d_model, vocab=args.vocab,
+                       ep_backend=args.ep_backend,
+                       wire_dtype=args.wire_dtype)
     dist = None
     if args.mesh == "local":
         mesh = make_bench_mesh(len(jax.devices()), model=args.local_model_axis)

@@ -13,7 +13,6 @@ from __future__ import annotations
 import math
 from typing import NamedTuple, Optional  # noqa: F401
 
-import repro.compat  # noqa: F401  jax version shims (jax.shard_map)
 import jax
 import jax.numpy as jnp
 from jax import lax

@@ -12,7 +12,6 @@ import math
 from functools import partial
 from typing import Optional
 
-import repro.compat  # noqa: F401  jax version shims (jax.shard_map)
 import jax
 import jax.numpy as jnp
 from jax import lax

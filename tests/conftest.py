@@ -12,7 +12,6 @@ SRC = str(Path(__file__).resolve().parents[1] / "src")
 if SRC not in sys.path:
     sys.path.insert(0, SRC)
 
-import repro.compat  # noqa: E402,F401  jax version shims (AxisType, shard_map)
 
 # ---- hypothesis shim -------------------------------------------------------
 # Property tests use hypothesis, which is a dev extra.  In a clean env the
@@ -91,7 +90,6 @@ def run_distributed(script: str, n_devices: int = 8, timeout: int = 900):
     env = dict(os.environ)
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={n_devices}"
     env["PYTHONPATH"] = SRC + os.pathsep + env.get("PYTHONPATH", "")
-    script = "import repro.compat  # jax version shims\n" + script
     proc = subprocess.run([sys.executable, "-c", script], env=env,
                           capture_output=True, text=True, timeout=timeout)
     if proc.returncode != 0:

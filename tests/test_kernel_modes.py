@@ -5,8 +5,9 @@ occupancy-aware counts contract — and the EP dispatch paths must deliver
 counts to the expert kernels and still match the dense oracle when the
 kernel bodies (not the jnp refs) execute.
 
-``scripts/ci.sh`` runs this module under ``REPRO_KERNEL_MODE=interpret`` so
-every CI run executes the Pallas kernels end-to-end, not just the refs.
+Each test names its mode with the ``mode=`` argument; without one the
+wrappers take :func:`repro.kernels.ops.platform_mode` (Pallas on the TPU,
+the refs elsewhere).
 """
 import jax
 import jax.numpy as jnp
@@ -99,18 +100,13 @@ def test_ops_gather_quantize_mode_parity(wdt):
         np.asarray(kops.dequantize_tokens(qi, si, mode="interpret")))
 
 
-def test_ops_swiglu_db_env_routing(monkeypatch):
-    """REPRO_SWIGLU_DB=1 routes kernel modes through the double-buffered
-    variant; results must stay on the masked-ref contract."""
-    e, c, d, f = 3, 24, 16, 13
-    x, _, _, wg, wu, wd = _problem(9, e, e * c, d, f, 1)
-    x = x[:e * c].reshape(e, c, d)
-    cnt = jnp.array([5, 0, 24], jnp.int32)
-    ref = kops.grouped_swiglu(x, wg, wu, wd, cnt, mode="ref")
-    monkeypatch.setenv("REPRO_SWIGLU_DB", "1")
-    got = kops.grouped_swiglu(x, wg, wu, wd, cnt, mode="interpret")
-    np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
-                               rtol=1e-4, atol=1e-5)
+def test_platform_mode_follows_backend():
+    """The default kernel mode is derived from the platform, not from an
+    environment variable: the jnp refs everywhere but the TPU."""
+    want = "pallas" if jax.default_backend() == "tpu" else "ref"
+    assert kops.platform_mode() == want
+    assert kops._mode(None) == want
+    assert kops._mode("interpret") == "interpret"
 
 
 def _mesh11():
@@ -151,19 +147,26 @@ def test_dispatch_delivers_counts_to_expert_fn(mode):
 
 @pytest.mark.parametrize("kernel_mode", ["ref", "interpret"])
 def test_moe_layer_kernel_mode_equivalence(kernel_mode, monkeypatch):
-    """The MoE layer through kops mode dispatch: interpret-mode kernel
+    """The MoE layer through kops mode dispatch on a one-device EP mesh:
+    with the platform's default mode steered to interpret, the kernel
     bodies (occupancy-aware grouped SwiGLU + fused gather/scatter) must
-    reproduce the ref-mode layer output."""
+    reproduce the dense ref-mode layer output."""
     from repro.configs import get_config, reduced_config
     from repro.core.moe import moe_apply, moe_init
+    from repro.distributed.sharding import make_dist_ctx
 
-    monkeypatch.setattr(kops, "KERNEL_MODE", kernel_mode)
+    monkeypatch.setattr(kops, "platform_mode", lambda: kernel_mode)
     cfg = reduced_config(get_config("qwen2_moe_a2_7b"), n_layers=2,
                          d_model=32, n_experts=4)
+    mesh = jax.make_mesh((1, 1), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
     p = moe_init(cfg, jax.random.PRNGKey(0))
     x = jax.random.normal(jax.random.PRNGKey(1), (2, 8, 32), jnp.float32)
     y_ref, _ = moe_apply(cfg, None, p, x, mode="ref")
-    y, aux = moe_apply(cfg, None, p, x, mode="ht", backend="simulated_rdma")
+    dist = make_dist_ctx(cfg, mesh)
+    y, aux = jax.jit(lambda p, x: moe_apply(cfg, dist, p, x, mode="ht",
+                                            backend="jax_collectives"))(p, x)
+    assert float(aux["dropped"]) == 0.0
     np.testing.assert_allclose(np.asarray(y), np.asarray(y_ref),
                                rtol=3e-4, atol=3e-5)
 

@@ -149,6 +149,29 @@ def test_gather_quantize_kernel_parity(wdt, e, c, d, t):
     assert (np.asarray(sk)[~occ] == 0).all()
 
 
+def test_kernel_f16_rounding_matches_cast():
+    """The kernel's f16 step (bit arithmetic in f32, since Mosaic has no
+    f16 on every TPU) equals the codec's f32 -> f16 cast over the clipped
+    fp8 range, ties included, and the fp8 bytes that follow are equal."""
+    from repro.kernels.quantize_pack import _round_to_f16
+
+    rng = np.random.default_rng(3)
+    y = rng.uniform(-448, 448, 200_000).astype(np.float32)
+    small = (rng.standard_normal(50_000) * 1e-3).astype(np.float32)
+    # f16 ties: halfway between neighbouring f16 values, both parities
+    h = rng.uniform(-448, 448, 50_000).astype(np.float16).astype(np.float32)
+    ties = (h.view(np.uint32) | np.uint32(0x1000)).view(np.float32)
+    y = np.concatenate([y, small, ties, np.float32([0.0, -0.0, 448, -448])])
+    got = np.asarray(_round_to_f16(jnp.asarray(y)))
+    want = y.astype(np.float16).astype(np.float32)
+    normal = np.abs(y) >= 2.0 ** -14
+    np.testing.assert_array_equal(got[normal], want[normal])
+    f8 = jnp.float8_e4m3fn
+    np.testing.assert_array_equal(
+        np.asarray(jnp.asarray(got).astype(f8)).view(np.uint8),
+        np.asarray(jnp.asarray(want).astype(f8)).view(np.uint8))
+
+
 def test_ops_gather_quantize_mode_parity():
     """The ops-level wrapper: ref and interpret modes agree bit-for-bit,
     and dequantize_tokens round-trips both."""
