@@ -202,56 +202,66 @@ def dispatch_combine_ll(spec: EPSpec, x: Array, top_idx: Array, top_w: Array,
     # expert more than once (e.g. random tables in tests)
     C = capacity or _cap(T * K / E, spec.capacity_factor, hard_max=T * K)
 
-    pl = planlib.make_plan(top_idx, E, C)
-    flat_e = top_idx.reshape(-1)                       # (T*K,)
-    valid, rank = pl.valid.reshape(-1), pl.rank.reshape(-1)
-    keep = pl.keep.reshape(-1)
-    slot = planlib.flat_slots(flat_e, rank, keep, C, E)  # overflow -> scratch
+    with jax.named_scope("moe.dispatch"):
+        pl = planlib.make_plan(top_idx, E, C)
+        flat_e = top_idx.reshape(-1)                       # (T*K,)
+        valid, rank = pl.valid.reshape(-1), pl.rank.reshape(-1)
+        keep = pl.keep.reshape(-1)
+        # overflow -> scratch
+        slot = planlib.flat_slots(flat_e, rank, keep, C, E)
 
-    # index-indirection packing (scatter ids, gather payloads; §Perf O2)
-    rows = jnp.arange(T * K, dtype=jnp.int32) // K
-    src_of_slot = jnp.full((E * C + 1,), T, jnp.int32).at[slot].set(
-        rows, mode="drop")[:-1]
-    # a2a over the (flattened) EP axes: expert e lives on flat shard e // eps.
-    if spec.wire_dtype == "fp32":
-        x_ext = jnp.concatenate([x.astype(spec.dtype),
-                                 jnp.zeros((1, D), spec.dtype)], axis=0)
-        send = x_ext[src_of_slot].reshape(P, eps * C, D)
-        recv = lax.all_to_all(send, spec.flat_axis(), split_axis=0,
-                              concat_axis=0, tiled=True)     # (P, eps*C, D)
-    else:
-        # compressed wire: quantize from the full-precision source (not the
-        # already-narrowed spec.dtype), dequantize to fp32 at the receiver
-        xf_ext = jnp.concatenate([x.astype(jnp.float32),
-                                  jnp.zeros((1, D), jnp.float32)], axis=0)
-        deq = _quantized_a2a(spec, xf_ext, src_of_slot,
-                             jnp.minimum(pl.counts, C), spec.flat_axis(), P)
-        recv = deq.astype(spec.dtype).reshape(P, eps * C, D)
-    recv = recv.reshape(P, eps, C, D).transpose(1, 0, 2, 3).reshape(eps, P * C, D)
+        # index-indirection packing (scatter ids, gather payloads; §Perf O2)
+        rows = jnp.arange(T * K, dtype=jnp.int32) // K
+        src_of_slot = jnp.full((E * C + 1,), T, jnp.int32).at[slot].set(
+            rows, mode="drop")[:-1]
+        # a2a over the (flattened) EP axes: expert e lives on flat shard
+        # e // eps.
+        if spec.wire_dtype == "fp32":
+            x_ext = jnp.concatenate([x.astype(spec.dtype),
+                                     jnp.zeros((1, D), spec.dtype)], axis=0)
+            send = x_ext[src_of_slot].reshape(P, eps * C, D)
+            recv = lax.all_to_all(send, spec.flat_axis(), split_axis=0,
+                                  concat_axis=0, tiled=True)  # (P, eps*C, D)
+        else:
+            # compressed wire: quantize from the full-precision source (not
+            # the already-narrowed spec.dtype), dequantize to fp32 at the
+            # receiver
+            xf_ext = jnp.concatenate([x.astype(jnp.float32),
+                                      jnp.zeros((1, D), jnp.float32)], axis=0)
+            deq = _quantized_a2a(spec, xf_ext, src_of_slot,
+                                 jnp.minimum(pl.counts, C), spec.flat_axis(),
+                                 P)
+            recv = deq.astype(spec.dtype).reshape(P, eps * C, D)
+        recv = recv.reshape(P, eps, C, D).transpose(1, 0, 2, 3).reshape(
+            eps, P * C, D)
 
-    # occupancy exchange: each source's per-(dest expert) occupied counts —
-    # the same metadata the paper's completion fences carry — so the expert
-    # kernel can skip the capacity padding (§Perf: occupancy-aware compute).
-    # recv bucket layout is (local expert, source bucket): counts (eps, P).
-    cnt_send = jnp.minimum(pl.counts, C).reshape(P, eps)
-    cnt_recv = lax.all_to_all(cnt_send, spec.flat_axis(), split_axis=0,
-                              concat_axis=0, tiled=True)       # (P, eps)
+        # occupancy exchange: each source's per-(dest expert) occupied
+        # counts — the same metadata the paper's completion fences carry — so
+        # the expert kernel can skip the capacity padding (§Perf:
+        # occupancy-aware compute).  recv bucket layout is (local expert,
+        # source bucket): counts (eps, P).
+        cnt_send = jnp.minimum(pl.counts, C).reshape(P, eps)
+        cnt_recv = lax.all_to_all(cnt_send, spec.flat_axis(), split_axis=0,
+                                  concat_axis=0, tiled=True)       # (P, eps)
     out_e = _call_expert_fn(expert_fn, recv, cnt_recv.T)  # (eps, P*C, D)
 
-    back = out_e.reshape(eps, P, C, D).transpose(1, 0, 2, 3).reshape(P, eps * C, D)
-    back = lax.all_to_all(back, spec.flat_axis(), split_axis=0, concat_axis=0,
-                          tiled=True)
-    back = back.reshape(E * C, D)
+    with jax.named_scope("moe.combine"):
+        back = out_e.reshape(eps, P, C, D).transpose(1, 0, 2, 3).reshape(
+            P, eps * C, D)
+        back = lax.all_to_all(back, spec.flat_axis(), split_axis=0,
+                              concat_axis=0, tiled=True)
+        back = back.reshape(E * C, D)
 
-    # combine: weighted fp32 segment-sum over the T*K kept choices — no
-    # (T, K, D) fp32 materialization + einsum, and no touching the (mostly
-    # padded) E*C slot space: each choice gathers its slot's row and
-    # scatter-adds into its token (dropped choices add 0 via the scratch row)
-    w_flat = jnp.where(keep, top_w.reshape(-1).astype(jnp.float32), 0.0)
-    contrib = back[jnp.where(keep, flat_e * C + rank, 0)].astype(
-        jnp.float32) * w_flat[:, None]
-    out = jnp.zeros((T + 1, D), jnp.float32).at[
-        jnp.where(keep, rows, T)].add(contrib)[:-1]
+        # combine: weighted fp32 segment-sum over the T*K kept choices — no
+        # (T, K, D) fp32 materialization + einsum, and no touching the
+        # (mostly padded) E*C slot space: each choice gathers its slot's row
+        # and scatter-adds into its token (dropped choices add 0 via the
+        # scratch row)
+        w_flat = jnp.where(keep, top_w.reshape(-1).astype(jnp.float32), 0.0)
+        contrib = back[jnp.where(keep, flat_e * C + rank, 0)].astype(
+            jnp.float32) * w_flat[:, None]
+        out = jnp.zeros((T + 1, D), jnp.float32).at[
+            jnp.where(keep, rows, T)].add(contrib)[:-1]
     dropped = pl.n_dropped / jnp.maximum(valid.sum(), 1)
     occupancy = jnp.minimum(pl.counts, C).sum() / (E * C)
     # global per-physical-slot load + imbalance (max/mean): the one stat the
@@ -429,18 +439,21 @@ def _ht_one_chunk(spec: EPSpec, x: Array, top_idx: Array, top_w: Array,
         eid_local = jnp.where(valid, top_idx % eps, NEG)
         frac = 1.0 - (1.0 - 1.0 / P) ** K
         C = _cap(T * frac, cf, hard_max=T)
-        plan = _dedup_group_dispatch(x, eid_local, top_w, group_of, P, C,
-                                     spec.dtype)
-        rx = _wire_dispatch_a2a(spec, x, plan, spec.axes[0], P, C)
-        re = lax.all_to_all(plan.send_eid, spec.axes[0], 0, 0, tiled=True)
-        rw = lax.all_to_all(plan.send_w, spec.axes[0], 0, 0, tiled=True)
+        with jax.named_scope("moe.dispatch"):
+            plan = _dedup_group_dispatch(x, eid_local, top_w, group_of, P, C,
+                                         spec.dtype)
+            rx = _wire_dispatch_a2a(spec, x, plan, spec.axes[0], P, C)
+            re = lax.all_to_all(plan.send_eid, spec.axes[0], 0, 0,
+                                tiled=True)
+            rw = lax.all_to_all(plan.send_w, spec.axes[0], 0, 0, tiled=True)
         part, d2, occ = _expert_apply(spec, rx.reshape(P * C, D),
                                       re.reshape(P * C, K),
                                       rw.reshape(P * C, K),
                                       expert_fn, cf, n_tokens_hint=T)
-        ret = lax.all_to_all(part.reshape(P, C, D).astype(spec.dtype),
-                             spec.axes[0], 0, 0, tiled=True)
-        out = _combine_scatter(plan, ret.astype(jnp.float32), T)
+        with jax.named_scope("moe.combine"):
+            ret = lax.all_to_all(part.reshape(P, C, D).astype(spec.dtype),
+                                 spec.axes[0], 0, 0, tiled=True)
+            out = _combine_scatter(plan, ret.astype(jnp.float32), T)
         return out, plan.dropped + d2, occ
 
     # ---- two-level: outer = pod (RDMA domain), inner = model (ICI domain) --
@@ -451,38 +464,43 @@ def _ht_one_chunk(spec: EPSpec, x: Array, top_idx: Array, top_w: Array,
     eid_in_pod = jnp.where(valid, top_idx % e_per_pod, NEG)
     frac_o = 1.0 - (1.0 - 1.0 / Po) ** K
     C1 = _cap(T * frac_o, cf, hard_max=T)
-    plan1 = _dedup_group_dispatch(x, eid_in_pod, top_w, pod_of, Po, C1,
-                                  spec.dtype)
-    # inter-pod a2a (same-rail: inner index unchanged), tokens cross once
-    rx = _wire_dispatch_a2a(spec, x, plan1, ax_o, Po, C1)       # (Po, C1, D)
-    re = lax.all_to_all(plan1.send_eid, ax_o, 0, 0, tiled=True)
-    rw = lax.all_to_all(plan1.send_w, ax_o, 0, 0, tiled=True)
     N2 = Po * C1
-    x2 = rx.reshape(N2, D)
-    e2 = re.reshape(N2, K)                 # expert ids within my pod
-    w2 = rw.reshape(N2, K)
-    # intra-pod forwarding: group by inner shard (NVLink-domain distribution)
-    v2 = e2 >= 0
-    grp2 = jnp.where(v2, e2 // eps, -1)
-    eid2 = jnp.where(v2, e2 % eps, NEG)
     frac_i = 1.0 - (1.0 - 1.0 / Pi) ** K
     C2 = _cap(N2 * frac_i, cf, hard_max=N2)
-    plan2 = _dedup_group_dispatch(x2, eid2, w2, grp2, Pi, C2, spec.dtype)
-    rx2 = _wire_dispatch_a2a(spec, x2, plan2, ax_i, Pi, C2)
-    re2 = lax.all_to_all(plan2.send_eid, ax_i, 0, 0, tiled=True)
-    rw2 = lax.all_to_all(plan2.send_w, ax_i, 0, 0, tiled=True)
+    with jax.named_scope("moe.dispatch"):
+        plan1 = _dedup_group_dispatch(x, eid_in_pod, top_w, pod_of, Po, C1,
+                                      spec.dtype)
+        # inter-pod a2a (same-rail: inner index unchanged), tokens cross once
+        rx = _wire_dispatch_a2a(spec, x, plan1, ax_o, Po, C1)  # (Po, C1, D)
+        re = lax.all_to_all(plan1.send_eid, ax_o, 0, 0, tiled=True)
+        rw = lax.all_to_all(plan1.send_w, ax_o, 0, 0, tiled=True)
+        x2 = rx.reshape(N2, D)
+        e2 = re.reshape(N2, K)                 # expert ids within my pod
+        w2 = rw.reshape(N2, K)
+        # intra-pod forwarding: group by inner shard (NVLink-domain
+        # distribution)
+        v2 = e2 >= 0
+        grp2 = jnp.where(v2, e2 // eps, -1)
+        eid2 = jnp.where(v2, e2 % eps, NEG)
+        plan2 = _dedup_group_dispatch(x2, eid2, w2, grp2, Pi, C2, spec.dtype)
+        rx2 = _wire_dispatch_a2a(spec, x2, plan2, ax_i, Pi, C2)
+        re2 = lax.all_to_all(plan2.send_eid, ax_i, 0, 0, tiled=True)
+        rw2 = lax.all_to_all(plan2.send_w, ax_i, 0, 0, tiled=True)
     part, d3, occ = _expert_apply(spec, rx2.reshape(Pi * C2, D),
                                   re2.reshape(Pi * C2, K),
                                   rw2.reshape(Pi * C2, K),
                                   expert_fn, cf, n_tokens_hint=T)
-    # hierarchical combine A: return partials intra-pod, reduce per (t, pod)
-    ret2 = lax.all_to_all(part.reshape(Pi, C2, D).astype(spec.dtype),
-                          ax_i, 0, 0, tiled=True)
-    red2 = _combine_scatter(plan2, ret2.astype(jnp.float32), N2)  # (N2, D)
-    # hierarchical combine B: ONE vector per (token, pod) crosses pods back
-    ret1 = lax.all_to_all(red2.reshape(Po, C1, D).astype(spec.dtype),
-                          ax_o, 0, 0, tiled=True)
-    out = _combine_scatter(plan1, ret1.astype(jnp.float32), T)
+    with jax.named_scope("moe.combine"):
+        # hierarchical combine A: return partials intra-pod, reduce per
+        # (t, pod)
+        ret2 = lax.all_to_all(part.reshape(Pi, C2, D).astype(spec.dtype),
+                              ax_i, 0, 0, tiled=True)
+        red2 = _combine_scatter(plan2, ret2.astype(jnp.float32), N2)
+        # hierarchical combine B: ONE vector per (token, pod) crosses pods
+        # back
+        ret1 = lax.all_to_all(red2.reshape(Po, C1, D).astype(spec.dtype),
+                              ax_o, 0, 0, tiled=True)
+        out = _combine_scatter(plan1, ret1.astype(jnp.float32), T)
     return out, plan1.dropped + plan2.dropped + d3, occ
 
 

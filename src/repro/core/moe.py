@@ -126,22 +126,27 @@ def moe_apply(cfg: ModelConfig, dist: Optional[DistCtx], p: dict, x: Array,
         y, aux = _moe_host_sim(cfg, dist, rparams, p, x, mode, ep_be)
     elif path == "dense":
         t = x.reshape(-1, D)
-        rout = route(mcfg, rparams, t, mcfg.n_experts)
-        y = moe_ref(t, rout.top_idx, rout.top_w, p["w_gate"], p["w_up"],
-                    p["w_down"])
-        load = planlib.expert_load(rout.top_idx, e_pad)
-        # imbalance over the REAL experts only: padded slots never receive
-        # tokens and would dilute the mean (4 real in 16 padded -> 4x)
-        aux = {"aux_loss": rout.aux_loss, "dropped": jnp.float32(0.0),
-               "load": load,
-               "imbalance": planlib.load_imbalance(load[:mcfg.n_experts])}
+        with jax.named_scope("moe.router"):
+            rout = route(mcfg, rparams, t, mcfg.n_experts)
+            load = planlib.expert_load(rout.top_idx, e_pad)
+            # imbalance over the REAL experts only: padded slots never
+            # receive tokens and would dilute the mean (4 real in 16
+            # padded -> 4x)
+            aux = {"aux_loss": rout.aux_loss, "dropped": jnp.float32(0.0),
+                   "load": load,
+                   "imbalance": planlib.load_imbalance(load[:mcfg.n_experts])}
+        with jax.named_scope("moe.experts"):
+            y = moe_ref(t, rout.top_idx, rout.top_w, p["w_gate"], p["w_up"],
+                        p["w_down"])
         y = y.reshape(B, S, D)
     else:
         y, aux = _moe_dist(cfg, dist, rparams, p, x, mode, chunks, ep_be)
 
     if mcfg.d_shared and "shared" in p:
-        sh = MLPParams(**{k: p["shared"][k] for k in ("w_gate", "w_up", "w_down")})
-        y = y + swiglu(sh, x)
+        with jax.named_scope("moe.shared"):
+            sh = MLPParams(**{k: p["shared"][k]
+                              for k in ("w_gate", "w_up", "w_down")})
+            y = y + swiglu(sh, x)
     return y, aux
 
 
@@ -208,10 +213,13 @@ def _moe_dist(cfg: ModelConfig, dist: DistCtx, rparams: RouterParams, p: dict,
     def island(x_l, rw, rb, wg, wu, wd):
         Bl, Sl, D = x_l.shape
         t = x_l.reshape(-1, D)
-        rout = route(mcfg, RouterParams(rw, rb), t, mcfg.n_experts)
-        fn = _expert_fn(wg, wu, wd)
-        res = ep_backend.dispatch_combine(spec, t, rout.top_idx, rout.top_w,
-                                          fn)
+        with jax.named_scope("moe.router"):
+            rout = route(mcfg, RouterParams(rw, rb), t, mcfg.n_experts)
+        # the backend scopes its exchanges moe.dispatch and moe.combine
+        with jax.named_scope("moe.experts"):
+            res = ep_backend.dispatch_combine(spec, t, rout.top_idx,
+                                              rout.top_w,
+                                              _expert_fn(wg, wu, wd))
         y = res.out.reshape(Bl, Sl, D)
         denom = jnp.float32(nshards)
         # global load via the shared helper (one definition for all three

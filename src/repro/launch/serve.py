@@ -30,6 +30,7 @@ from jax.sharding import NamedSharding
 from repro.configs import ModelConfig, cut_config, get_config, reduced_config
 from repro.distributed.sharding import (DistCtx, cache_pspecs, make_dist_ctx,
                                         param_shardings)
+from repro.launch.tracing import gc_spans, span
 from repro.models import model_zoo as Z
 
 
@@ -83,15 +84,21 @@ def compile_steps(cfg: ModelConfig, dist: Optional[DistCtx], params: dict,
     """Ahead-of-time compiled ``(prefill, step)`` for these shapes.
     ``prefill`` is None where the prompt goes through the decode step (a
     model-axis mesh shards the cache; mamba stacks)."""
-    step = jax.jit(partial(Z.decode_step, cfg, dist=dist, moe_mode="ll"),
-                   donate_argnums=(1,))
-    step = step.lower(params, cache, prompts[:, :1], jnp.int32(0)).compile()
-    prefill = None
-    if not cfg.mamba.enabled and (dist is None or dist.model_axis is None):
-        prefill = jax.jit(partial(Z.prefill, cfg, moe_mode="ht"),
-                          donate_argnums=(1,))
-        prefill = prefill.lower(params, cache, prompts).compile()
-    return prefill, step
+    # named functions, so that the programs read jit_decode_step and
+    # jit_prefill in a profiler trace
+    def decode_step(params, cache, tokens, pos):
+        return Z.decode_step(cfg, params, cache, tokens, pos, dist=dist,
+                             moe_mode="ll")
+
+    def prefill(params, cache, tokens):
+        return Z.prefill(cfg, params, cache, tokens, moe_mode="ht")
+
+    step = jax.jit(decode_step, donate_argnums=(1,)).lower(
+        params, cache, prompts[:, :1], jnp.int32(0)).compile()
+    if cfg.mamba.enabled or (dist is not None and dist.model_axis is not None):
+        return None, step
+    return jax.jit(prefill, donate_argnums=(1,)).lower(
+        params, cache, prompts).compile(), step
 
 
 @partial(jax.jit, static_argnums=1)
@@ -105,23 +112,36 @@ def generate(cfg: ModelConfig, prefill, step, params: dict, cache: dict,
 
     Returns ``(tokens (B, gen) int32, logits (B, gen, V_pad) f32)``: row
     ``i`` of the logits is the distribution token ``i`` was drawn from.
-    ``cache`` is donated."""
+    ``cache`` is donated.  Host spans (``launch/tracing.py``):
+    ``repro.serve.prefill`` round the prompt, ``repro.serve.step`` round
+    each decode-step call, ``repro.serve.sample`` round each greedy pick,
+    ``repro.serve.stack`` round the final stacking, ``repro.host.gc`` round
+    each garbage collection."""
     S = prompts.shape[1]
-    if prefill is not None:
-        logits, cache = prefill(params, cache, prompts)
-    else:
-        for t in range(S - 1):
-            _, cache = step(params, cache, prompts[:, t:t + 1], jnp.int32(t))
-        logits, cache = step(params, cache, prompts[:, -1:], jnp.int32(S - 1))
-    logits_all = [logits]
-    tok = _greedy(logits, cfg.vocab_size)
-    tokens = [tok]
-    for t in range(S, S + gen - 1):
-        logits, cache = step(params, cache, tok, jnp.int32(t))
-        logits_all.append(logits)
-        tok = _greedy(logits, cfg.vocab_size)
-        tokens.append(tok)
-    return jnp.concatenate(tokens, axis=1), jnp.stack(logits_all, axis=1)
+    with gc_spans():
+        with span("repro.serve.prefill"):
+            if prefill is not None:
+                logits, cache = prefill(params, cache, prompts)
+            else:
+                for t in range(S):
+                    with span("repro.serve.step", step=t):
+                        logits, cache = step(params, cache,
+                                             prompts[:, t:t + 1],
+                                             jnp.int32(t))
+        logits_all = [logits]
+        with span("repro.serve.sample"):
+            tok = _greedy(logits, cfg.vocab_size)
+        tokens = [tok]
+        for t in range(S, S + gen - 1):
+            with span("repro.serve.step", step=t):
+                logits, cache = step(params, cache, tok, jnp.int32(t))
+            logits_all.append(logits)
+            with span("repro.serve.sample"):
+                tok = _greedy(logits, cfg.vocab_size)
+            tokens.append(tok)
+        with span("repro.serve.stack"):
+            return (jnp.concatenate(tokens, axis=1),
+                    jnp.stack(logits_all, axis=1))
 
 
 def main(argv=None):

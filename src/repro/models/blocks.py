@@ -56,6 +56,12 @@ def _attn_params(cfg: ModelConfig, d: dict) -> AttnParams:
                       q_norm=d.get("q_norm"), k_norm=d.get("k_norm"))
 
 
+def _mixer_scope(p: dict) -> str:
+    """The layer scope of a block's token mixer (``ln1`` through its
+    output projection)."""
+    return "mamba" if "mamba" in p else "attention"
+
+
 def block_apply(cfg: ModelConfig, dist: Optional[DistCtx], p: dict, x: Array,
                 positions: Array, *, moe_mode: str = "ht",
                 moe_chunks: int = 1, causal_skip: bool = False,
@@ -74,20 +80,21 @@ def block_apply(cfg: ModelConfig, dist: Optional[DistCtx], p: dict, x: Array,
     aux = {}
     bd = dist.batch_axes if dist else None
     use_islands = sp_islands and _islands_ok(cfg, dist, x)
-    h = rmsnorm(x, p["ln1"], cfg.norm_eps)
-    if "attn" in p:
-        if use_islands:
-            h = _attention_island(cfg, dist, p["attn"], h, positions,
-                                  causal_skip=causal_skip)
-        else:
-            h = _c(dist, h, bd, None, None)          # gather seq (SP)
-            h = attention(cfg, _attn_params(cfg, p["attn"]), h, positions,
-                          causal_skip=causal_skip)
+    with jax.named_scope(_mixer_scope(p)):
+        h = rmsnorm(x, p["ln1"], cfg.norm_eps)
+        if "attn" in p:
+            if use_islands:
+                h = _attention_island(cfg, dist, p["attn"], h, positions,
+                                      causal_skip=causal_skip)
+            else:
+                h = _c(dist, h, bd, None, None)          # gather seq (SP)
+                h = attention(cfg, _attn_params(cfg, p["attn"]), h,
+                              positions, causal_skip=causal_skip)
+                h = _c(dist, h, bd, dist.seq_axis if dist else None, None)
+        elif "mamba" in p:
+            h = _c(dist, h, bd, None, None)
+            h = mamba_mod.mamba_apply(cfg, p["mamba"], h)
             h = _c(dist, h, bd, dist.seq_axis if dist else None, None)
-    elif "mamba" in p:
-        h = _c(dist, h, bd, None, None)
-        h = mamba_mod.mamba_apply(cfg, p["mamba"], h)
-        h = _c(dist, h, bd, dist.seq_axis if dist else None, None)
     x = x + h
 
     h = rmsnorm(x, p["ln2"], cfg.norm_eps)
@@ -287,26 +294,28 @@ def block_decode(cfg: ModelConfig, dist: Optional[DistCtx], p: dict,
                  *, moe_mode: str = "ll") -> tuple[Array, BlockCache, dict]:
     """One-token decode: x (B, 1, D)."""
     aux = {}
-    h = rmsnorm(x, p["ln1"], cfg.norm_eps)
-    if "attn" in p:
-        ap = _attn_params(cfg, p["attn"])
-        q, k_new, v_new = decode_qkv(cfg, ap, h, pos)
-        if dist is not None and dist.model_axis:
-            o, cache = _decode_attn_dist(dist, q, k_new, v_new, cache, pos)
-        else:
-            kc = lax.dynamic_update_slice_in_dim(
-                cache.k, k_new.astype(cache.k.dtype), pos, 1)
-            vc = lax.dynamic_update_slice_in_dim(
-                cache.v, v_new.astype(cache.v.dtype), pos, 1)
-            cache = cache._replace(k=kc, v=vc)
-            part = decode_attention_local(q, kc, vc, pos)
-            l = jnp.where(part.l == 0, 1.0, part.l)
-            o = (part.o / l[..., None]).astype(h.dtype)
-        h = jnp.einsum("bshk,hkd->bsd", o, ap.wo.astype(h.dtype))
-    elif "mamba" in p:
-        mc = mamba_mod.MambaCache(conv=cache.conv, ssm=cache.ssm)
-        h, mc = mamba_mod.mamba_decode_step(cfg, p["mamba"], h, mc)
-        cache = cache._replace(conv=mc.conv, ssm=mc.ssm)
+    with jax.named_scope(_mixer_scope(p)):
+        h = rmsnorm(x, p["ln1"], cfg.norm_eps)
+        if "attn" in p:
+            ap = _attn_params(cfg, p["attn"])
+            q, k_new, v_new = decode_qkv(cfg, ap, h, pos)
+            if dist is not None and dist.model_axis:
+                o, cache = _decode_attn_dist(dist, q, k_new, v_new, cache,
+                                             pos)
+            else:
+                kc = lax.dynamic_update_slice_in_dim(
+                    cache.k, k_new.astype(cache.k.dtype), pos, 1)
+                vc = lax.dynamic_update_slice_in_dim(
+                    cache.v, v_new.astype(cache.v.dtype), pos, 1)
+                cache = cache._replace(k=kc, v=vc)
+                part = decode_attention_local(q, kc, vc, pos)
+                l = jnp.where(part.l == 0, 1.0, part.l)
+                o = (part.o / l[..., None]).astype(h.dtype)
+            h = jnp.einsum("bshk,hkd->bsd", o, ap.wo.astype(h.dtype))
+        elif "mamba" in p:
+            mc = mamba_mod.MambaCache(conv=cache.conv, ssm=cache.ssm)
+            h, mc = mamba_mod.mamba_decode_step(cfg, p["mamba"], h, mc)
+            cache = cache._replace(conv=mc.conv, ssm=mc.ssm)
     x = x + h
 
     h = rmsnorm(x, p["ln2"], cfg.norm_eps)
@@ -334,24 +343,25 @@ def block_prefill(cfg: ModelConfig, dist: Optional[DistCtx], p: dict,
     distributed decode loop.
     """
     aux = {}
-    h = rmsnorm(x, p["ln1"], cfg.norm_eps)
-    if "attn" in p:
-        ap = _attn_params(cfg, p["attn"])
-        q, k_new, v_new = _qkv(cfg, ap, h, positions)
-        S = x.shape[1]
-        blk = min(512, S)
-        o = flash_attention_blocked(q, k_new, v_new, causal=True,
-                                    q_block=blk, kv_block=blk)
-        kc = lax.dynamic_update_slice_in_dim(
-            cache.k, k_new.astype(cache.k.dtype), 0, 1)
-        vc = lax.dynamic_update_slice_in_dim(
-            cache.v, v_new.astype(cache.v.dtype), 0, 1)
-        cache = cache._replace(k=kc, v=vc)
-        h = jnp.einsum("bshk,hkd->bsd", o, ap.wo.astype(h.dtype))
-    elif "mamba" in p:
-        raise NotImplementedError(
-            "batched prefill needs the post-prompt recurrent state; mamba "
-            "layers prefill through the per-token decode loop")
+    with jax.named_scope("attention"):
+        h = rmsnorm(x, p["ln1"], cfg.norm_eps)
+        if "attn" in p:
+            ap = _attn_params(cfg, p["attn"])
+            q, k_new, v_new = _qkv(cfg, ap, h, positions)
+            S = x.shape[1]
+            blk = min(512, S)
+            o = flash_attention_blocked(q, k_new, v_new, causal=True,
+                                        q_block=blk, kv_block=blk)
+            kc = lax.dynamic_update_slice_in_dim(
+                cache.k, k_new.astype(cache.k.dtype), 0, 1)
+            vc = lax.dynamic_update_slice_in_dim(
+                cache.v, v_new.astype(cache.v.dtype), 0, 1)
+            cache = cache._replace(k=kc, v=vc)
+            h = jnp.einsum("bshk,hkd->bsd", o, ap.wo.astype(h.dtype))
+        elif "mamba" in p:
+            raise NotImplementedError(
+                "batched prefill needs the post-prompt recurrent state; "
+                "mamba layers prefill through the per-token decode loop")
     x = x + h
 
     h = rmsnorm(x, p["ln2"], cfg.norm_eps)
@@ -369,6 +379,12 @@ def block_prefill(cfg: ModelConfig, dist: Optional[DistCtx], p: dict,
 # ---------------------------------------------- vocab-parallel embedding --
 def vocab_embed(dist: Optional[DistCtx], embed: Array, tokens: Array) -> Array:
     """tokens (B, S) -> (B, S, D); embed (V_pad, D) sharded P("model", None)."""
+    with jax.named_scope("embed"):
+        return _vocab_embed(dist, embed, tokens)
+
+
+def _vocab_embed(dist: Optional[DistCtx], embed: Array,
+                 tokens: Array) -> Array:
     if dist is None or dist.model_axis is None:
         return jnp.take(embed, tokens, axis=0)
     from repro.distributed.sharding import effective_batch_axes

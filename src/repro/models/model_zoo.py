@@ -57,7 +57,8 @@ def cast_params(params: dict, dtype) -> dict:
                                     "D", "conv_b")):
             return x.astype(dtype)
         return x
-    return jax.tree_util.tree_map_with_path(f, params)
+    with jax.named_scope("cast"):
+        return jax.tree_util.tree_map_with_path(f, params)
 
 
 # --------------------------------------------------------------- forward --
@@ -120,7 +121,8 @@ def forward(cfg: ModelConfig, params: dict, tokens: Array,
     aux = {"aux_loss": aux_s["aux_loss"].sum(),
            "dropped": aux_s["dropped"].mean() if cfg.moe.enabled else jnp.float32(0.0),
            "loads": aux_s["loads"]}  # per slot: (n_periods, E) expert loads
-    x = rmsnorm(x, params["final_ln"], cfg.norm_eps)
+    with jax.named_scope("lm_head"):
+        x = rmsnorm(x, params["final_ln"], cfg.norm_eps)
     return x, aux
 
 
@@ -266,11 +268,12 @@ def prefill(cfg: ModelConfig, params: dict, cache: dict, tokens: Array,
         new_cache = jax.tree.map(lambda *xs: jnp.stack(xs), *caches)
     else:
         x, new_cache = lax.scan(period_body, x, (cparams["blocks"], cache))
-    x = rmsnorm(x, cparams["final_ln"], cfg.norm_eps)
-    head = lm_head_weight(cfg, cparams)
-    logits = (x[:, -1] @ head).astype(jnp.float32)
-    if dist is not None:
-        logits = dist.constraint(logits, dist.batch_axes, dist.model_axis)
+    with jax.named_scope("lm_head"):
+        x = rmsnorm(x, cparams["final_ln"], cfg.norm_eps)
+        head = lm_head_weight(cfg, cparams)
+        logits = (x[:, -1] @ head).astype(jnp.float32)
+        if dist is not None:
+            logits = dist.constraint(logits, dist.batch_axes, dist.model_axis)
     return logits, new_cache
 
 
@@ -309,9 +312,10 @@ def decode_step(cfg: ModelConfig, params: dict, cache: dict, tokens: Array,
         new_cache = jax.tree.map(lambda *xs: jnp.stack(xs), *caches)
     else:
         x, new_cache = lax.scan(period_body, x, (cparams["blocks"], cache))
-    x = rmsnorm(x, cparams["final_ln"], cfg.norm_eps)
-    head = lm_head_weight(cfg, cparams)
-    logits = (x[:, 0] @ head).astype(jnp.float32)
-    if dist is not None:
-        logits = dist.constraint(logits, dist.batch_axes, dist.model_axis)
+    with jax.named_scope("lm_head"):
+        x = rmsnorm(x, cparams["final_ln"], cfg.norm_eps)
+        head = lm_head_weight(cfg, cparams)
+        logits = (x[:, 0] @ head).astype(jnp.float32)
+        if dist is not None:
+            logits = dist.constraint(logits, dist.batch_axes, dist.model_axis)
     return logits, new_cache

@@ -1,8 +1,11 @@
 """Launchers and ``chip_smoke.py`` on the CPU: config cuts, mesh and cache
 placement, the compile-cache directory, and both smoke phases at a tiny
 size (the chip runs them at full width)."""
+import dataclasses
+import gc
 import importlib.util
 import os
+import re
 import subprocess
 import sys
 import textwrap
@@ -135,3 +138,181 @@ def test_chip_smoke_four_chip_phase_tiny(dist_runner):
     assert "FOUR-OK" in out
     for mode in ("ht/fp32", "ht/fp8", "ll/fp32", "ll/fp8"):
         assert f"moe layer {mode}" in out
+
+
+# the layer scopes (jax.named_scope) of the one-chip path and of the EP path
+ONE_CHIP_SCOPES = {"cast", "embed", "attention", "moe.router", "moe.experts",
+                   "moe.shared", "lm_head"}
+EP_SCOPES = {"moe.router", "moe.dispatch", "moe.experts", "moe.combine"}
+
+
+def _scopes(hlo: str) -> set:
+    return {part for path in re.findall(r'op_name="([^"]*)"', hlo)
+            for part in path.split("/")} & (ONE_CHIP_SCOPES | EP_SCOPES)
+
+
+def _tiny_shared():
+    cfg = _tiny()
+    return dataclasses.replace(
+        cfg, moe=dataclasses.replace(cfg.moe, d_shared=64))
+
+
+def _served(cfg, batch=2, prompt=6, gen=4):
+    params = serve.init_params(cfg, None, jax.random.PRNGKey(0))
+    prompts = jax.random.randint(jax.random.PRNGKey(1), (batch, prompt), 0,
+                                 cfg.vocab_size)
+    prefill, step = serve.compile_steps(
+        cfg, None, params, serve.new_cache(cfg, None, batch, prompt + gen),
+        prompts)
+    return params, prompts, prefill, step
+
+
+def test_compile_steps_names_the_programs_and_scopes_each_layer():
+    _, _, prefill, step = _served(_tiny_shared())
+    for program, name in ((prefill, "jit_prefill"), (step, "jit_decode_step")):
+        hlo = program.as_text()
+        assert hlo.startswith(f"HloModule {name},")
+        assert _scopes(hlo) == ONE_CHIP_SCOPES
+
+
+def _repro_spans(logdir) -> list:
+    """(name, start_ns, end_ns, stats) of the host spans named repro.* in
+    the one trace under ``logdir``, in order of start."""
+    from jax.profiler import ProfileData
+
+    found = list(Path(logdir).glob("**/*.xplane.pb"))
+    assert len(found) == 1
+    pd = ProfileData.from_file(str(found[0]))
+    return sorted(((e.name, e.start_ns, e.start_ns + e.duration_ns,
+                    dict(e.stats))
+                   for plane in pd.planes if plane.name.startswith("/host:")
+                   for line in plane.lines for e in line.events
+                   if e.name.startswith("repro.")), key=lambda s: s[1])
+
+
+@pytest.mark.parametrize("batched", [True, False])
+def test_generate_emits_serve_spans_in_order(batched, tmp_path):
+    cfg = _tiny()
+    S, gen = 6, 4
+    params, prompts, prefill, step = _served(cfg, prompt=S, gen=gen)
+
+    def collecting_step(*args):
+        gc.collect()
+        return step(*args)
+
+    with jax.profiler.trace(str(tmp_path)):
+        tokens, logits = serve.generate(
+            cfg, prefill if batched else None, collecting_step, params,
+            serve.new_cache(cfg, None, 2, S + gen), prompts, gen)
+        jax.block_until_ready((tokens, logits))
+    spans = _repro_spans(tmp_path)
+    serve_spans = [s for s in spans if s[0] != "repro.host.gc"]
+    prompt_steps = [] if batched else ["repro.serve.step"] * S
+    assert [s[0] for s in serve_spans] == (
+        ["repro.serve.prefill", *prompt_steps, "repro.serve.sample"]
+        + ["repro.serve.step", "repro.serve.sample"] * (gen - 1)
+        + ["repro.serve.stack"])
+    steps = [s for s in serve_spans if s[0] == "repro.serve.step"]
+    assert [s[3]["step"] for s in steps] == list(
+        range(S if batched else 0, S + gen - 1))
+    prefill_span = serve_spans[0]
+    for _, lo, hi, _ in steps[:len(prompt_steps)]:
+        assert prefill_span[1] <= lo and hi <= prefill_span[2]
+    # each step's collection shows as a repro.host.gc span inside its step
+    collections = [s for s in spans if s[0] == "repro.host.gc"]
+    for _, lo, hi, _ in steps:
+        assert any(lo <= c[1] and c[2] <= hi for c in collections)
+
+
+def test_generate_removes_its_gc_callback_on_return_and_on_error():
+    cfg = _tiny()
+    params, prompts, prefill, step = _served(cfg)
+    before = list(gc.callbacks)
+    seen = []
+
+    def watched_step(*args):
+        seen.append(len(gc.callbacks))
+        return step(*args)
+
+    serve.generate(cfg, prefill, watched_step, params,
+                   serve.new_cache(cfg, None, 2, 10), prompts, 4)
+    assert seen and set(seen) == {len(before) + 1}
+    assert gc.callbacks == before
+
+    def failing_step(*args):
+        raise RuntimeError("step failed")
+
+    with pytest.raises(RuntimeError, match="step failed"):
+        serve.generate(cfg, prefill, failing_step, params,
+                       serve.new_cache(cfg, None, 2, 10), prompts, 4)
+    assert gc.callbacks == before
+
+
+def test_ep_path_scopes_its_exchanges(dist_runner):
+    """On a (1, 4) mesh the MoE layer runs the EP island: every all-to-all
+    lies under moe.dispatch or moe.combine, in LL, HT and two-level HT."""
+    out = dist_runner(textwrap.dedent("""
+        import dataclasses, re
+        from functools import partial
+        import jax, jax.numpy as jnp
+        from jax.sharding import AxisType, PartitionSpec as P
+        from repro.configs import get_config, reduced_config
+        from repro.core.ep import EPSpec, dispatch_combine_ht
+        from repro.core.moe import moe_apply, moe_init
+        from repro.distributed.sharding import make_dist_ctx
+        from repro.kernels.ref import grouped_swiglu_ref
+        from repro.launch.mesh import make_bench_mesh
+
+        def paths(hlo):
+            return re.findall(r'op_name="([^"]*)"', hlo)
+
+        def a2a_paths(hlo):
+            return [m for line in hlo.splitlines() if " all-to-all(" in line
+                    for m in re.findall(r'op_name="([^"]*)"', line)]
+
+        cfg = reduced_config(get_config("moonshot_v1_16b_a3b"), n_layers=2,
+                             d_model=64, n_experts=8, vocab=512)
+        cfg = dataclasses.replace(
+            cfg, moe=dataclasses.replace(cfg.moe, d_shared=64))
+        mesh = make_bench_mesh(4, model=4)
+        dist = make_dist_ctx(cfg, mesh)
+        p = moe_init(cfg, jax.random.PRNGKey(0))
+        x = jnp.ones((4, 16, cfg.d_model), jnp.float32)
+        for mode in ("ll", "ht"):
+            with jax.set_mesh(mesh):
+                hlo = jax.jit(partial(moe_apply, cfg, dist, mode=mode)).lower(
+                    p, x).compile().as_text()
+            scopes = {part for path in paths(hlo) for part in path.split("/")}
+            want = {"moe.router", "moe.dispatch", "moe.experts",
+                    "moe.combine", "moe.shared"}
+            assert want <= scopes, (mode, want - scopes)
+            a2a = a2a_paths(hlo)
+            assert a2a and all("/moe.dispatch/" in a or "/moe.combine/" in a
+                               for a in a2a), (mode, a2a)
+            print(f"SCOPES-{mode}-OK")
+
+        mesh2 = jax.make_mesh((2, 2), ("pod", "model"),
+                              axis_types=(AxisType.Auto,) * 2)
+        E, K, D, F, T = 8, 2, 16, 24, 16
+        spec = EPSpec(axes=("pod", "model"), sizes=(2, 2), n_experts=E,
+                      top_k=K, capacity_factor=4.0, dtype=jnp.float32)
+        w = jnp.ones((E, D, F)), jnp.ones((E, D, F)), jnp.ones((E, F, D))
+
+        def island(x, ti, tw, wg, wu, wd):
+            return dispatch_combine_ht(
+                spec, x, ti, tw,
+                lambda t: grouped_swiglu_ref(t, wg, wu, wd)).out
+
+        ax, ep = ("pod", "model"), P(("pod", "model"), None, None)
+        hlo = jax.jit(jax.shard_map(
+            island, mesh=mesh2, in_specs=(P(ax), P(ax), P(ax), ep, ep, ep),
+            out_specs=P(ax), check_vma=False)).lower(
+            jnp.ones((T, D)), jnp.zeros((T, K), jnp.int32),
+            jnp.ones((T, K)), *w).compile().as_text()
+        a2a = a2a_paths(hlo)
+        assert len(a2a) >= 6 and all(
+            "/moe.dispatch/" in a or "/moe.combine/" in a for a in a2a), a2a
+        print("SCOPES-two-level-OK")
+    """), n_devices=4, timeout=600)
+    for tag in ("ll", "ht", "two-level"):
+        assert f"SCOPES-{tag}-OK" in out

@@ -7,8 +7,16 @@ refuses what interpret mode accepts — blocks off the (8, 128) tiling,
 loads Mosaic cannot lower, more VMEM than the scoped limit.  The topology
 is described inside a fixture (never at import), so under several pytest
 workers only the worker given this file loads the TPU library.
+
+The decode step of the benchmark's ``moonlight-2l`` configuration is
+compiled whole, to hold its layer scopes (``jax.named_scope``) where the
+benchmark's per-layer readers look for them.
 """
 import os
+import re
+import sys
+from functools import partial
+from pathlib import Path
 
 import jax
 import jax.numpy as jnp
@@ -102,3 +110,46 @@ def test_decode_attention_compiles(one_chip):
     kv = ((4, 4096, HEADS, HEAD_DIM), BF16)
     _compile(one_chip, decode_attention_pallas, ((4, HEADS, HEAD_DIM), BF16),
              kv, kv, ((), I32))
+
+
+# result type and dims, opcode and JAX path of an HLO instruction
+HLO_OP = re.compile(r"= (\w+)\[([\d,]*)\]\S* ([\w-]+)\(.*op_name=\"([^\"]*)\"")
+
+
+def test_decode_step_keeps_casts_and_expert_dots_in_their_scopes(one_chip):
+    """Every convert of a stacked expert weight lies under ``cast``, every
+    dot of the routed experts under ``moe.experts`` (batch 8, so that no
+    token dim reads as E or F)."""
+    sys.path.insert(0, str(Path(__file__).resolve().parents[1]))
+    from bench import cells
+    from repro.models import model_zoo as Z
+
+    cfg = cells.model_config(cells.resolve("moonlight.decode").config)
+    assert (cfg.d_model, cfg.moe.d_expert, cfg.moe.n_experts) == (D, F, E)
+
+    def shapes(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=one_chip), tree)
+
+    params = shapes(jax.eval_shape(partial(Z.init_params, cfg),
+                                   jax.random.PRNGKey(0)))
+    cache = shapes(jax.eval_shape(partial(Z.init_cache, cfg, 8, 16, BF16)))
+    tokens, pos = shapes((jax.ShapeDtypeStruct((8, 1), I32),
+                          jax.ShapeDtypeStruct((), I32)))
+
+    def decode_step(params, cache, tokens, pos):
+        return Z.decode_step(cfg, params, cache, tokens, pos, moe_mode="ll")
+
+    hlo = jax.jit(decode_step).lower(params, cache, tokens,
+                                     pos).compile().as_text()
+    casts, dots = [], []
+    for dtype, dims, opcode, path in HLO_OP.findall(hlo):
+        dims = tuple(int(d) for d in dims.split(",") if d)
+        if opcode == "convert" and dims[-3:] in ((E, D, F), (E, F, D)):
+            casts.append((dtype, path))
+        if opcode in ("dot", "convolution") and E in dims and (
+                F in dims or D in dims):
+            dots.append(path)
+    assert len(casts) == 3 and all(
+        dt == "bf16" and "/cast/" in p for dt, p in casts), casts
+    assert len(dots) >= 3 and all("/moe.experts/" in p for p in dots), dots
