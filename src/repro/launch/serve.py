@@ -79,13 +79,74 @@ def new_cache(cfg: ModelConfig, dist: Optional[DistCtx], batch: int,
         lambda s: NamedSharding(dist.mesh, s), specs))()
 
 
+class ServedWeights:
+    """The weights the model-step programs read: the caller's master tree
+    cast to the compute dtype (``model_zoo.cast_params``) by ``cast``, once
+    per master tree.
+
+    A call with the same master leaves as the last (by identity) returns
+    the kept copy; other leaves run ``cast`` once, inside a host span
+    ``repro.serve.cast``, and count in ``casts``.  Where the cast would
+    change no leaf (masters already in the compute dtype), ``cast`` is None
+    and the masters are served as they are.  The masters' leaves are held
+    as long as the copy."""
+
+    def __init__(self, cast):
+        self.cast = cast
+        self.casts = 0
+        self._masters = None        # (leaves, treedef) of the last masters
+        self._served = None
+
+    def __call__(self, params: dict) -> dict:
+        if self.cast is None:
+            return params
+        leaves, tree = jax.tree.flatten(params)
+        last = self._masters
+        if last is None or tree != last[1] or any(
+                a is not b for a, b in zip(leaves, last[0])):
+            with span("repro.serve.cast"):
+                self._served = self.cast(params)
+            self._masters = leaves, tree
+            self.casts += 1
+        return self._served
+
+
+@dataclasses.dataclass(frozen=True)
+class ServedProgram:
+    """A compiled model-step program (``compiled``) called with the master
+    weights: ``weights`` resolves them to the served copy it takes."""
+    compiled: object
+    weights: ServedWeights
+
+    def __call__(self, params: dict, *args):
+        return self.compiled(self.weights(params), *args)
+
+    def as_text(self) -> str:
+        return self.compiled.as_text()
+
+    def memory_analysis(self):
+        return self.compiled.memory_analysis()
+
+
 def compile_steps(cfg: ModelConfig, dist: Optional[DistCtx], params: dict,
                   cache: dict, prompts):
-    """Ahead-of-time compiled ``(prefill, step)`` for these shapes.
+    """Ahead-of-time compiled ``(prefill, step)`` for these shapes, called
+    as ``prefill(params, cache, prompts)`` and ``step(params, cache,
+    tokens, pos)`` with the master weights ``params``.
+
+    The programs take the served weights, the masters cast to the compute
+    dtype by a third program, ``jit_serve_weights`` (each leaf keeps its
+    sharding), run once per master tree and kept by the
+    :class:`ServedWeights` both share; dropping both frees the copy.
     ``prefill`` is None where the prompt goes through the decode step (a
     model-axis mesh shards the cache; mamba stacks)."""
-    # named functions, so that the programs read jit_decode_step and
-    # jit_prefill in a profiler trace
+    dtype = jnp.dtype(cfg.dtype)
+
+    # named functions, so that the programs read jit_serve_weights,
+    # jit_decode_step and jit_prefill in a profiler trace
+    def serve_weights(params):
+        return Z.cast_params(params, dtype)
+
     def decode_step(params, cache, tokens, pos):
         return Z.decode_step(cfg, params, cache, tokens, pos, dist=dist,
                              moe_mode="ll")
@@ -93,12 +154,24 @@ def compile_steps(cfg: ModelConfig, dist: Optional[DistCtx], params: dict,
     def prefill(params, cache, tokens):
         return Z.prefill(cfg, params, cache, tokens, moe_mode="ht")
 
+    shapes = jax.eval_shape(serve_weights, params)
+    cast = None
+    if any(a.dtype != b.dtype for a, b in zip(jax.tree.leaves(params),
+                                             jax.tree.leaves(shapes))):
+        shardings = jax.tree.map(lambda x: x.sharding, params)
+        cast = jax.jit(serve_weights, out_shardings=shardings).lower(
+            params).compile()
+        params = jax.tree.map(
+            lambda s, sh: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=sh),
+            shapes, shardings)
+    weights = ServedWeights(cast)
     step = jax.jit(decode_step, donate_argnums=(1,)).lower(
         params, cache, prompts[:, :1], jnp.int32(0)).compile()
+    step = ServedProgram(step, weights)
     if cfg.mamba.enabled or (dist is not None and dist.model_axis is not None):
         return None, step
-    return jax.jit(prefill, donate_argnums=(1,)).lower(
-        params, cache, prompts).compile(), step
+    return ServedProgram(jax.jit(prefill, donate_argnums=(1,)).lower(
+        params, cache, prompts).compile(), weights), step
 
 
 @partial(jax.jit, static_argnums=1)
@@ -108,15 +181,19 @@ def _greedy(logits, vocab: int):
 
 def generate(cfg: ModelConfig, prefill, step, params: dict, cache: dict,
              prompts, gen: int):
-    """Greedy generation of ``gen`` tokens after ``prompts`` (B, S).
+    """Greedy generation of ``gen`` tokens after ``prompts`` (B, S), with
+    ``prefill`` and ``step`` from :func:`compile_steps`, called with the
+    master weights ``params``: the programs take the served bf16 copy, cast
+    on the first call with a new master tree and kept after it.
 
     Returns ``(tokens (B, gen) int32, logits (B, gen, V_pad) f32)``: row
     ``i`` of the logits is the distribution token ``i`` was drawn from.
     ``cache`` is donated.  Host spans (``launch/tracing.py``):
-    ``repro.serve.prefill`` round the prompt, ``repro.serve.step`` round
-    each decode-step call, ``repro.serve.sample`` round each greedy pick,
-    ``repro.serve.stack`` round the final stacking, ``repro.host.gc`` round
-    each garbage collection."""
+    ``repro.serve.prefill`` round the prompt, ``repro.serve.cast`` round a
+    cast of the masters (inside the first call that needs it),
+    ``repro.serve.step`` round each decode-step call, ``repro.serve.sample``
+    round each greedy pick, ``repro.serve.stack`` round the final stacking,
+    ``repro.host.gc`` round each garbage collection."""
     S = prompts.shape[1]
     with gc_spans():
         with span("repro.serve.prefill"):

@@ -2,6 +2,7 @@
 placement, the compile-cache directory, and both smoke phases at a tiny
 size (the chip runs them at full width)."""
 import dataclasses
+import functools
 import gc
 import importlib.util
 import os
@@ -19,6 +20,7 @@ from repro.configs import get_config, reduced_config
 from repro.launch import serve
 from repro.launch.compile_cache import REPO_CACHE_DIR, enable_compile_cache
 from repro.launch.mesh import make_bench_mesh
+from repro.models import model_zoo as Z
 
 ROOT = Path(__file__).resolve().parents[1]
 
@@ -168,11 +170,74 @@ def _served(cfg, batch=2, prompt=6, gen=4):
 
 
 def test_compile_steps_names_the_programs_and_scopes_each_layer():
+    """The step programs carry every one-chip scope but ``cast``: the
+    weights come cast by their own program, which carries ``cast``."""
     _, _, prefill, step = _served(_tiny_shared())
-    for program, name in ((prefill, "jit_prefill"), (step, "jit_decode_step")):
+    assert prefill.weights is step.weights
+    for program, name, scopes in (
+            (prefill, "jit_prefill", ONE_CHIP_SCOPES - {"cast"}),
+            (step, "jit_decode_step", ONE_CHIP_SCOPES - {"cast"}),
+            (step.weights.cast, "jit_serve_weights", {"cast"})):
         hlo = program.as_text()
         assert hlo.startswith(f"HloModule {name},")
-        assert _scopes(hlo) == ONE_CHIP_SCOPES
+        assert _scopes(hlo) == scopes
+
+
+def _casting_steps(cfg):
+    """The model-step programs jitted on the f32 masters: each call casts
+    the masters it is given."""
+    return (jax.jit(functools.partial(Z.prefill, cfg, moe_mode="ht")),
+            jax.jit(functools.partial(Z.decode_step, cfg, moe_mode="ll")))
+
+
+@pytest.mark.parametrize("batched", [True, False])
+def test_served_weights_give_the_casting_steps_tokens_and_logits_bitwise(batched):
+    """Serving from the copy cast once changes no arithmetic: in bf16, the
+    tokens and logits equal, bit for bit, those of programs that cast the
+    masters in every call."""
+    cfg = _tiny()
+    assert cfg.dtype == "bfloat16"
+    params, prompts, prefill, step = _served(cfg)
+    ref_prefill, ref_step = _casting_steps(cfg)
+    got, want = (serve.generate(cfg, pre if batched else None, st, params,
+                                serve.new_cache(cfg, None, 2, 10), prompts, 4)
+                 for pre, st in ((prefill, step), (ref_prefill, ref_step)))
+    for a, b in zip(got, want):
+        assert a.dtype == b.dtype
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    assert step.weights.casts == 1
+
+
+def test_served_weights_are_cast_once_per_master_tree():
+    cfg = _tiny()
+    params, prompts, prefill, step = _served(cfg)
+
+    def run(params):
+        return serve.generate(cfg, prefill, step, params,
+                              serve.new_cache(cfg, None, 2, 10), prompts, 4)
+
+    first = run(params)
+    run(params)
+    assert step.weights.casts == 1
+    served = step.weights(params)
+    assert step.weights.casts == 1
+    assert served["embed"].dtype == jax.numpy.bfloat16
+    assert served["final_ln"].dtype == jax.numpy.float32   # norms stay f32
+    # a new tree of the same leaves is the same masters; new leaves are not
+    assert step.weights(dict(params)) is served
+    again = run(jax.tree.map(lambda x: x + 0, params))
+    assert step.weights.casts == 2
+    np.testing.assert_array_equal(np.asarray(first[1]), np.asarray(again[1]))
+
+
+def test_f32_compute_serves_the_masters_as_they_are():
+    cfg = dataclasses.replace(_tiny(), dtype="float32")
+    params, prompts, prefill, step = _served(cfg)
+    assert step.weights.cast is None
+    assert step.weights(params) is params
+    serve.generate(cfg, prefill, step, params,
+                   serve.new_cache(cfg, None, 2, 10), prompts, 4)
+    assert step.weights.casts == 0
 
 
 def _repro_spans(logdir) -> list:
@@ -192,6 +257,8 @@ def _repro_spans(logdir) -> list:
 
 @pytest.mark.parametrize("batched", [True, False])
 def test_generate_emits_serve_spans_in_order(batched, tmp_path):
+    """On fresh programs the first call casts the masters, inside the
+    prompt's span; a second call with the same masters casts nothing."""
     cfg = _tiny()
     S, gen = 6, 4
     params, prompts, prefill, step = _served(cfg, prompt=S, gen=gen)
@@ -200,28 +267,79 @@ def test_generate_emits_serve_spans_in_order(batched, tmp_path):
         gc.collect()
         return step(*args)
 
-    with jax.profiler.trace(str(tmp_path)):
-        tokens, logits = serve.generate(
-            cfg, prefill if batched else None, collecting_step, params,
-            serve.new_cache(cfg, None, 2, S + gen), prompts, gen)
-        jax.block_until_ready((tokens, logits))
-    spans = _repro_spans(tmp_path)
-    serve_spans = [s for s in spans if s[0] != "repro.host.gc"]
-    prompt_steps = [] if batched else ["repro.serve.step"] * S
-    assert [s[0] for s in serve_spans] == (
-        ["repro.serve.prefill", *prompt_steps, "repro.serve.sample"]
-        + ["repro.serve.step", "repro.serve.sample"] * (gen - 1)
-        + ["repro.serve.stack"])
-    steps = [s for s in serve_spans if s[0] == "repro.serve.step"]
-    assert [s[3]["step"] for s in steps] == list(
-        range(S if batched else 0, S + gen - 1))
-    prefill_span = serve_spans[0]
-    for _, lo, hi, _ in steps[:len(prompt_steps)]:
-        assert prefill_span[1] <= lo and hi <= prefill_span[2]
-    # each step's collection shows as a repro.host.gc span inside its step
-    collections = [s for s in spans if s[0] == "repro.host.gc"]
-    for _, lo, hi, _ in steps:
-        assert any(lo <= c[1] and c[2] <= hi for c in collections)
+    for call in (0, 1):
+        logdir = tmp_path / f"call{call}"
+        with jax.profiler.trace(str(logdir)):
+            tokens, logits = serve.generate(
+                cfg, prefill if batched else None, collecting_step, params,
+                serve.new_cache(cfg, None, 2, S + gen), prompts, gen)
+            jax.block_until_ready((tokens, logits))
+        spans = _repro_spans(logdir)
+        serve_spans = [s for s in spans if s[0] != "repro.host.gc"]
+        prompt_steps = [] if batched else ["repro.serve.step"] * S
+        cast = ["repro.serve.cast"] if call == 0 else []
+        # the cast starts inside the call that needs it: the prefill, or
+        # the prompt's first step
+        assert [s[0] for s in serve_spans] == (
+            ["repro.serve.prefill", *prompt_steps[:1], *cast,
+             *prompt_steps[1:], "repro.serve.sample"]
+            + ["repro.serve.step", "repro.serve.sample"] * (gen - 1)
+            + ["repro.serve.stack"])
+        casts = [s for s in serve_spans if s[0] == "repro.serve.cast"]
+        serve_spans = [s for s in serve_spans if s[0] != "repro.serve.cast"]
+        steps = [s for s in serve_spans if s[0] == "repro.serve.step"]
+        assert [s[3]["step"] for s in steps] == list(
+            range(S if batched else 0, S + gen - 1))
+        prefill_span = serve_spans[0]
+        for _, lo, hi, _ in steps[:len(prompt_steps)] + casts:
+            assert prefill_span[1] <= lo and hi <= prefill_span[2]
+        if casts and not batched:
+            assert steps[0][1] <= casts[0][1] and casts[0][2] <= steps[0][2]
+        # each step's collection shows as a repro.host.gc span inside its
+        # step
+        collections = [s for s in spans if s[0] == "repro.host.gc"]
+        for _, lo, hi, _ in steps:
+            assert any(lo <= c[1] and c[2] <= hi for c in collections)
+    assert step.weights.casts == 1
+
+
+def test_served_weights_keep_their_shardings_on_a_mesh(dist_runner):
+    """On a (1, 4) mesh the served copy keeps each master leaf's sharding,
+    and the prompt through the sharded step gives the tokens and logits of
+    a step that casts the masters in every call, bit for bit, with one
+    cast."""
+    out = dist_runner(textwrap.dedent("""
+        from functools import partial
+        import jax, numpy as np
+        from repro.configs import get_config, reduced_config
+        from repro.distributed.sharding import make_dist_ctx
+        from repro.launch import serve
+        from repro.launch.mesh import make_bench_mesh
+        from repro.models import model_zoo as Z
+
+        cfg = reduced_config(get_config("moonshot_v1_16b_a3b"), n_layers=2,
+                             d_model=64, n_experts=8, vocab=512)
+        dist = make_dist_ctx(cfg, make_bench_mesh(4, model=4))
+        params = serve.init_params(cfg, dist, jax.random.PRNGKey(0))
+        prompts = jax.random.randint(jax.random.PRNGKey(1), (4, 4), 0,
+                                     cfg.vocab_size)
+        prefill, step = serve.compile_steps(
+            cfg, dist, params, serve.new_cache(cfg, dist, 4, 8), prompts)
+        assert prefill is None
+        served = step.weights(params)
+        for m, s in zip(jax.tree.leaves(params), jax.tree.leaves(served)):
+            assert s.sharding == m.sharding, (m.sharding, s.sharding)
+        ref_step = jax.jit(partial(Z.decode_step, cfg, dist=dist,
+                                   moe_mode="ll"))
+        got, want = (serve.generate(cfg, None, st, params,
+                                    serve.new_cache(cfg, dist, 4, 8),
+                                    prompts, 4) for st in (step, ref_step))
+        for a, b in zip(got, want):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+        assert step.weights.casts == 1
+        print("MESH-OK")
+    """), n_devices=4, timeout=600)
+    assert "MESH-OK" in out
 
 
 def test_generate_removes_its_gc_callback_on_return_and_on_error():
