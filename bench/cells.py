@@ -3,14 +3,17 @@ configuration file, its traffic mix, its correctness limits and the readers
 of its per-layer metrics.  Nothing about one cell is written in code: a new
 cell, mix or metric is a new file and a new entry.
 
-  bench/configs/<config>.json   sizes, cuts, deviations, deployment
+  bench/configs/<config>.json   sizes, cuts, deviations, deployment, and
+                                optionally its mesh and its reference
   bench/traffic/<traffic>.json  loop, batch, prompt, gen
   bench/checks/<workload>.json  what the correctness check samples, limits
   bench/metrics/<metric>.py     one reader per per-layer metric
+  bench/<reference>.py          a configuration's plain reference
 """
 from __future__ import annotations
 
 import dataclasses
+import functools
 import importlib.util
 import json
 from dataclasses import dataclass
@@ -64,14 +67,41 @@ def resolve(name: str, root: Path = REPO_DIR) -> Cell:
         per_layer=[m for m in spec["per_layer"] if _in_cell(m, name)])
 
 
-def metric_reader(name: str, root: Path = REPO_DIR) -> ModuleType:
-    """``bench/metrics/<name>.py``, which defines ``read(ctx)``."""
-    path = root / "bench" / "metrics" / f"{name}.py"
-    mod_spec = importlib.util.spec_from_file_location(
-        f"bench_metric_{name.replace('.', '_').replace('-', '_')}", path)
+@functools.lru_cache(maxsize=None)
+def _load(path: Path, prefix: str) -> ModuleType:
+    name = path.stem.replace(".", "_").replace("-", "_")
+    mod_spec = importlib.util.spec_from_file_location(f"{prefix}_{name}", path)
     mod = importlib.util.module_from_spec(mod_spec)
     mod_spec.loader.exec_module(mod)
     return mod
+
+
+def metric_reader(name: str, root: Path = REPO_DIR) -> ModuleType:
+    """``bench/metrics/<name>.py``, which defines ``read(ctx)``."""
+    return _load(root / "bench" / "metrics" / f"{name}.py", "bench_metric")
+
+
+def reference(config: dict, root: Optional[Path] = None) -> ModuleType:
+    """The plain reference that a configuration file names under
+    ``reference`` (``bench/<module>.py``; default ``reference``).  It
+    defines ``logits(config, params, seqs, first, precision)`` and may
+    define ``FAN_IN``, leaf name -> the axes of its shape whose product is
+    the leaf's fan-in (``bench/weights.py``)."""
+    name = config.get("reference", "reference")
+    return _load((root or REPO_DIR) / "bench" / f"{name}.py",
+                 "bench_reference")
+
+
+def mesh_shape(config: dict, chips: int):
+    """``(data, model)`` of the configuration's ``mesh``, or None where it
+    runs on one chip.  The product has to be the cell's chips."""
+    if "mesh" not in config:
+        return None
+    shape = (int(config["mesh"]["data"]), int(config["mesh"]["model"]))
+    if shape[0] * shape[1] != chips:
+        raise ValueError(f"{config['name']}: mesh {config['mesh']} does not "
+                         f"hold the cell's {chips} chips")
+    return shape
 
 
 def model_config(config: dict, override: Optional[dict] = None):

@@ -7,13 +7,14 @@ Greedy decoding at the reference's precision would give gaps of 0; the
 program's bfloat16 arithmetic may pick a token whose reference logit lies a
 little below the best where two logits nearly tie.
 ``bench/checks/<cell>.json`` gives the number of requests sampled and a
-limit for each number of :func:`numbers` that is compared.
+limit for each number of :func:`numbers` that is compared.  The reference
+is the module that the configuration file names (``cells.reference``).
 """
 from __future__ import annotations
 
 import numpy as np
 
-from bench import reference
+from bench import cells
 
 
 def sequences(prompts, tokens):
@@ -33,8 +34,9 @@ def gaps(ref_logits, chosen):
 def served_gaps(config: dict, params, prompts, tokens,
                 precision: str = "f32"):
     """Per served token (R, G): its gap under the reference."""
-    ref = reference.logits(config, params, sequences(prompts, tokens),
-                           prompts.shape[1] - 1, precision)
+    ref = cells.reference(config).logits(
+        config, params, sequences(prompts, tokens), prompts.shape[1] - 1,
+        precision)
     return gaps(ref, tokens)
 
 
@@ -42,6 +44,7 @@ def control_gaps(config: dict, params, prompts, tokens,
                  precision: str = "fp8"):
     """Per position (R, G): the gap, under the float32 reference, of the
     token that the reference computed in ``precision`` puts first."""
+    reference = cells.reference(config)
     seqs = sequences(prompts, tokens)
     first = prompts.shape[1] - 1
     ref = reference.logits(config, params, seqs, first, "f32")

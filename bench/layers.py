@@ -19,8 +19,9 @@ harness's ``bench.*`` spans.
 
 ``load`` gives a ``trace.Trace`` that holds both, so ``trace.breakdown``
 of it names an idle gap by the program's innermost span.  ``bench/run.py``
-loads its trace with ``trace.load``, which keeps neither: no cell reports
-``METRICS`` yet, and this module's command reads them from a kept trace.
+loads its traces with it; the readers ``bench/metrics/<name>.py`` of the
+per-layer metrics named in ``METRICS`` return its readings, and this
+module's command prints them all for a kept trace.
 """
 from __future__ import annotations
 
@@ -166,12 +167,6 @@ def load(path) -> ScopedTrace:
     return from_xspace(data)
 
 
-def leaves(ops) -> list:
-    """``trace.leaves`` of the ops of some duration: an op of none that
-    starts where a long op starts would make that op read as its body."""
-    return trace.leaves([o for o in ops if o[2] > o[1]])
-
-
 def layer_scope(path: str):
     """The innermost of ``LAYER_SCOPES`` on an op's JAX path, or None."""
     for part in reversed(path.split("/")):
@@ -194,6 +189,12 @@ def named_calls(tr: trace.Trace, device: int, function: str) -> list:
     return sorted(out, key=lambda c: c[1])
 
 
+def _leaves(tr: ScopedTrace, device: int) -> list:
+    if ("leaves", device) not in tr.memo:
+        tr.memo[("leaves", device)] = trace.leaves(tr.devices[device].ops)
+    return tr.memo[("leaves", device)]
+
+
 def scope_seconds(tr: ScopedTrace, device: int, function: str) -> list:
     """Per call, inside the window, of ``jit_<function>``: the seconds of
     its leaf ops by layer scope, ``{scope: s}`` (``None`` for ops under no
@@ -201,13 +202,11 @@ def scope_seconds(tr: ScopedTrace, device: int, function: str) -> list:
     program without ``named_scope``s, or a trace without op paths)."""
     key = ("scope_seconds", device, function)
     if key not in tr.memo:
-        if ("leaves", device) not in tr.memo:
-            tr.memo[("leaves", device)] = leaves(tr.devices[device].ops)
         paths = tr.paths.get(device, {})
         calls = named_calls(tr, device, function)
         per = [{} for _ in calls]
         scoped, j = False, 0
-        for n, s, e in tr.memo[("leaves", device)]:
+        for n, s, e in _leaves(tr, device):
             while j < len(calls) and calls[j][2] < s:
                 j += 1
             if j == len(calls):
@@ -237,6 +236,33 @@ def scope_ms(tr: ScopedTrace, function: str, select):
     return 1e3 * sum(per_chip) / len(per_chip)
 
 
+def op_seconds(tr: ScopedTrace, device: int, function: str,
+               prefix: str) -> list:
+    """Device seconds of each leaf op whose HLO name starts with
+    ``prefix`` (``grouped_swiglu_pallas``: each call of that kernel) inside
+    the calls, in the window, of ``jit_<function>``."""
+    calls = named_calls(tr, device, function)
+    out, j = [], 0
+    for n, s, e in _leaves(tr, device):
+        while j < len(calls) and calls[j][2] < s:
+            j += 1
+        if j == len(calls):
+            break
+        if calls[j][1] <= s and e <= calls[j][2] and n.startswith(prefix):
+            out.append((e - s) * 1e-9)
+    return out
+
+
+def op_ms(tr: ScopedTrace, function: str, prefix: str):
+    """Median over the ops of :func:`op_seconds`, in ms, the mean over the
+    chips that ran any.  None where none ran."""
+    per_chip = [statistics.median(ops) for ops in (
+        op_seconds(tr, d, function, prefix) for d in tr.devices) if ops]
+    if not per_chip:
+        return None
+    return 1e3 * sum(per_chip) / len(per_chip)
+
+
 def span_seconds(tr: trace.Trace, name: str) -> list:
     """Host seconds of each span ``name`` inside the window."""
     lo, hi = tr.window
@@ -261,7 +287,9 @@ def serve_step_host_ms(tr: trace.Trace):
 # write), the MoE layer's scopes and no layer scope (the layer scan's own
 # copies and slices, norms and residual adds between the layers); the same
 # of the batched prefill (``jit_prefill``) under the MoE layer's scopes;
-# the host time of a decode-step call.
+# the host time of a decode-step call; the decode step's time under the EP
+# exchanges (``moe.dispatch``, ``moe.combine``: the all-to-alls and their
+# packing and combining) and of one call of the grouped expert kernel.
 METRICS = {
     "decode_cast_ms": lambda tr: scope_ms(tr, "decode_step",
                                           lambda s: s == "cast"),
@@ -272,6 +300,10 @@ METRICS = {
                                               lambda s: s is None),
     "prefill_moe_ms": lambda tr: scope_ms(tr, "prefill", _is_moe),
     "serve_step_host_ms": serve_step_host_ms,
+    "a2a_ms_per_step": lambda tr: scope_ms(
+        tr, "decode_step", lambda s: s in ("moe.dispatch", "moe.combine")),
+    "expert_kernel_ms": lambda tr: op_ms(tr, "decode_step",
+                                         "grouped_swiglu_pallas"),
 }
 
 

@@ -1,14 +1,17 @@
 """Compile a cell's serving programs for a described TPU v5e, without the
-chip, and print what ``memory_analysis()`` says of each.
+chip, and print what ``memory_analysis()`` says of each, per chip.
 
   JAX_PLATFORMS=cpu python3 bench/rehearse.py moonlight.decode [more cells]
 
-Each cell compiles for one device of a described ``v5e:2x2``.  The
-programs are the ones ``repro.launch.serve.compile_steps`` builds on one
-chip (``model_zoo.decode_step`` in LL mode, ``model_zoo.prefill`` in HT
-mode), lowered from shapes alone, with the Pallas kernels the chip would
-run.
-Nothing runs, so nothing here is a time.
+The programs are the ones the harness serves with:
+``repro.launch.serve.compile_steps``' ``jit_serve_weights`` (the f32
+masters cast once to the served bf16 copy), ``jit_decode_step`` and
+``jit_prefill`` (none on a model-axis mesh, where the prompt goes through
+the step), lowered from shapes alone, with the Pallas kernels the chip
+would run.  A cell on one chip compiles for one device of a described
+``v5e:2x2``; a configuration with a ``mesh`` compiles for that mesh over
+the topology's devices, with the weights placed by ``param_shardings`` and
+the KV cache by ``cache_pspecs``.  Nothing runs, so nothing here is a time.
 """
 from __future__ import annotations
 
@@ -26,37 +29,72 @@ for p in (str(REPO), str(REPO / "src")):
 GB = 1e9
 
 
-def rehearse(name: str, topo) -> dict:
+def _prompts(shape, sharding):
+    """Shapes of a prompt batch that ``compile_steps`` can slice (it lowers
+    the step on ``prompts[:, :1]``)."""
     import jax
     import jax.numpy as jnp
-    from jax.sharding import SingleDeviceSharding
+
+    class Prompts(jax.ShapeDtypeStruct):
+        def __getitem__(self, index):
+            import numpy as np
+
+            return jax.ShapeDtypeStruct(
+                np.empty(self.shape, np.int8)[index].shape, self.dtype,
+                sharding=self.sharding)
+
+    return Prompts(shape, jnp.int32, sharding=sharding)
+
+
+def rehearse(name: str, topo) -> dict:
+    """The cell's compiled programs by name, for the described devices."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from jax.sharding import (AxisType, Mesh, NamedSharding, PartitionSpec,
+                              SingleDeviceSharding)
 
     from bench import cells
+    from repro.distributed.sharding import (cache_pspecs, make_dist_ctx,
+                                            param_shardings)
+    from repro.launch import serve
     from repro.models import model_zoo as Z
 
     cell = cells.resolve(name)
     cfg = cells.model_config(cell.config)
     t = cell.traffic
     B, S, max_len = t["batch"], t["prompt"], t["prompt"] + t["gen"]
-    one = SingleDeviceSharding(topo.devices[0])
-
-    def sds(shapes):
-        return jax.tree.map(lambda s: jax.ShapeDtypeStruct(
-            s.shape, s.dtype, sharding=one), shapes)
-
     key = jax.ShapeDtypeStruct((2,), jnp.uint32)
-    params = sds(jax.eval_shape(partial(Z.init_params, cfg), key))
-    cache = sds(jax.eval_shape(partial(Z.init_cache, cfg, B, max_len,
-                                       jnp.dtype(cfg.dtype))))
-    tok = jax.ShapeDtypeStruct((B, 1), jnp.int32, sharding=one)
-    prompts = jax.ShapeDtypeStruct((B, S), jnp.int32, sharding=one)
-    step = jax.jit(partial(Z.decode_step, cfg, moe_mode="ll"),
-                   donate_argnums=(1,))
-    pre = jax.jit(partial(Z.prefill, cfg, moe_mode="ht"),
-                  donate_argnums=(1,))
-    return {"decode_step": step.lower(params, cache, tok,
-                                      jnp.int32(0)).compile(),
-            "prefill": pre.lower(params, cache, prompts).compile()}
+    params = jax.eval_shape(partial(Z.init_params, cfg), key)
+    cache = jax.eval_shape(partial(Z.init_cache, cfg, B, max_len,
+                                   jnp.dtype(cfg.dtype)))
+    shape = cells.mesh_shape(cell.config, cell.chips)
+    dist = None
+    if shape is None:
+        one = SingleDeviceSharding(topo.devices[0])
+        p_sh = jax.tree.map(lambda _: one, params)
+        c_sh = jax.tree.map(lambda _: one, cache)
+        t_sh = one
+    else:
+        mesh = Mesh(np.array(topo.devices[:cell.chips]).reshape(shape),
+                    ("data", "model"), axis_types=(AxisType.Auto,) * 2)
+        dist = make_dist_ctx(cfg, mesh)
+        p_sh = param_shardings(cfg, dist, params)
+        c_sh = jax.tree.map(lambda s: NamedSharding(mesh, s),
+                            cache_pspecs(cfg, dist, cache, B))
+        t_sh = NamedSharding(mesh, PartitionSpec())
+
+    def sds(shapes, shardings):
+        return jax.tree.map(lambda s, sh: jax.ShapeDtypeStruct(
+            s.shape, s.dtype, sharding=sh), shapes, shardings)
+
+    prefill, step = serve.compile_steps(cfg, dist, sds(params, p_sh),
+                                        sds(cache, c_sh),
+                                        _prompts((B, S), t_sh))
+    out = {"serve_weights": step.weights.cast, "decode_step": step.compiled}
+    if prefill is not None:
+        out["prefill"] = prefill.compiled
+    return out
 
 
 def main(argv=None) -> int:
@@ -78,13 +116,15 @@ def main(argv=None) -> int:
             ma = c.memory_analysis()
             total = (ma.argument_size_in_bytes + ma.temp_size_in_bytes
                      + ma.output_size_in_bytes - ma.alias_size_in_bytes)
-            print(f"{name} {prog}: arguments "
+            text = c.as_text()
+            print(f"{name} {prog}: per chip arguments "
                   f"{ma.argument_size_in_bytes / GB:.3f} GB, temporaries "
                   f"{ma.temp_size_in_bytes / GB:.3f} GB, outputs "
                   f"{ma.output_size_in_bytes / GB:.3f} GB, aliased "
-                  f"{ma.alias_size_in_bytes / GB:.3f} GB; per device "
+                  f"{ma.alias_size_in_bytes / GB:.3f} GB; in all "
                   f"{total / GB:.3f} GB; pallas calls "
-                  f"{c.as_text().count('tpu_custom_call')}", flush=True)
+                  f"{text.count('tpu_custom_call')}, all-to-alls "
+                  f"{text.count(' all-to-all(')}", flush=True)
     return 0
 
 

@@ -5,18 +5,20 @@
 The cell is found by name in ``BENCHMARK.json``; its configuration, traffic,
 correctness limits and per-layer metric readers are files under ``bench/``
 (see ``bench/cells.py``).  One process: it enables the repo's compile cache,
-makes the weights on the device from the seed, compiles the cell's programs,
-serves one warm-up round, then serves rounds for ``--seconds``
-(``bench/serve_loop.py``).  After the window it reads the device's peak
+makes the weights on the device from the seed (over the cell's chips where
+the configuration names a ``mesh``), compiles the cell's programs, serves
+one warm-up round, then serves rounds for ``--seconds``
+(``bench/serve_loop.py``).  After the window it reads the devices' peak
 memory, frees the programs and checks a sample of the served tokens against
-the plain reference (``bench/check.py``).
+the configuration's plain reference (``bench/check.py``).
 
 The last line of standard output is one JSON object: ``correct``,
 ``attempted``, ``failed``, ``metrics`` (the cell's end-to-end metrics, or
 with ``--trace 1`` its per-layer metrics read from a profiler trace of the
-window), ``device``, with ``--trace 1`` ``breakdown``, and last ``checks``:
-each number compared with its limit.  Without a TPU, or with fewer chips
-than the cell asks for, it exits 1 and prints no result.
+window, which then ends early once it holds ``TRACE_LAYER_CALLS``),
+``device``, with ``--trace 1`` ``breakdown``, and last ``checks``: each
+number compared with its limit.  Without a TPU, or with fewer chips than the
+cell asks for, it exits 1 and prints no result.
 
 ``--control`` runs the correctness check's control: the same run, with the
 tokens that the plain reference computed in float8 puts first compared in
@@ -46,6 +48,11 @@ for p in (str(REPO), str(REPO / "src")):
 from bench import cells  # noqa: E402
 
 SETUP_SPLIT = ("start", "weights", "compile", "warm_up")
+# A traced window ends after the round in which its program calls x layers x
+# chips reach this: past it a trace grows too large to write and read within
+# a run's time.  A whole window of a one-chip cell stays under it (about
+# 12,500 in the decode cells); the four-chip cell reaches it in 2 rounds.
+TRACE_LAYER_CALLS = 16000
 
 
 def log(msg: str) -> None:
@@ -86,6 +93,16 @@ def end_to_end(name: str, win, traffic: dict, setup_s: float) -> float:
     if name == "setup_s":
         return setup_s
     raise KeyError(f"no end-to-end metric {name!r} in bench/run.py")
+
+
+def traced_rounds(traffic: dict, n_layers: int, chips: int,
+                  prefill: bool) -> int:
+    """Rounds after which a traced window ends (``TRACE_LAYER_CALLS``): a
+    round calls the prefill program once, or the step for each prompt
+    position where there is none, then the step for all but the last token
+    (``serve.generate``)."""
+    calls = traffic["gen"] - 1 + (1 if prefill else traffic["prompt"])
+    return -(-TRACE_LAYER_CALLS // (calls * n_layers * chips))
 
 
 def sample_for_check(server, win, check: dict, seed: int):
@@ -142,11 +159,20 @@ def run_cell(args, *, require_tpu: bool = True, override=None):
     from bench import check, serve_loop, weights
 
     cfg = cells.model_config(config, override)
+    dist = None
+    shape = cells.mesh_shape(config, cell.chips)
+    if shape is not None:
+        from repro.distributed.sharding import make_dist_ctx
+        from repro.launch.mesh import make_bench_mesh
+
+        dist = make_dist_ctx(cfg, make_bench_mesh(cell.chips, model=shape[1]))
+    fan_in = getattr(cells.reference(config), "FAN_IN", None)
     t = time.perf_counter()
-    params = jax.block_until_ready(weights.make_params(cfg, args.seed))
+    params = jax.block_until_ready(weights.make_params(cfg, args.seed, dist,
+                                                       fan_in))
     split["weights"] = time.perf_counter() - t
     t = time.perf_counter()
-    server = serve_loop.Server(cfg, params, traffic, args.seed)
+    server = serve_loop.Server(cfg, params, traffic, args.seed, dist)
     split["compile"] = time.perf_counter() - t
     t = time.perf_counter()
     server.round(-1)
@@ -165,7 +191,9 @@ def run_cell(args, *, require_tpu: bool = True, override=None):
         trace_dir = args.trace_dir or tempfile.mkdtemp(prefix="bench-trace-")
         jax.profiler.start_trace(trace_dir)
         server.probe()
-    win = server.run(args.seconds)
+    win = server.run(args.seconds, traced_rounds(
+        traffic, cfg.n_layers, cell.chips, server.prefill is not None)
+        if args.trace else None)
     if args.trace:
         jax.profiler.stop_trace()
     used = devices[:cell.chips]
@@ -205,9 +233,9 @@ def run_cell(args, *, require_tpu: bool = True, override=None):
               "memory_peak_bytes": mem}
     metrics = {}
     if args.trace:
-        from bench import trace
+        from bench import layers, trace
 
-        tr = trace.load(trace_dir)
+        tr = layers.load(trace_dir)
         if args.trace_dir is None:
             shutil.rmtree(trace_dir, ignore_errors=True)
         busy = trace.busy_s(tr)

@@ -20,6 +20,7 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 from jax.profiler import TraceAnnotation
+from jax.sharding import NamedSharding
 
 from bench import weights
 
@@ -54,9 +55,12 @@ class Window:
 
 class Server:
     """The cell's compiled programs, weights and prompt stream on one
-    chip."""
+    chip, or on the mesh of ``dist`` (a ``DistCtx``), where the KV cache is
+    placed by ``cache_pspecs`` and the prompt goes through the decode step
+    (``serve.compile_steps`` builds no prefill on a model-axis mesh)."""
 
-    def __init__(self, cfg, params, traffic: dict, seed: int):
+    def __init__(self, cfg, params, traffic: dict, seed: int, dist=None):
+        from repro.distributed.sharding import cache_pspecs
         from repro.launch import serve
         from repro.models import model_zoo as Z
 
@@ -69,11 +73,15 @@ class Server:
         # the initialiser that serve.new_cache wraps, compiled once:
         # new_cache jits it afresh on every call, which would trace and
         # compile it (or load it from the compile cache) in every round
-        self.new_cache = jax.jit(partial(
-            Z.init_cache, cfg, self.batch, self.max_len,
-            jnp.dtype(cfg.dtype))).lower().compile()
+        init = partial(Z.init_cache, cfg, self.batch, self.max_len,
+                       jnp.dtype(cfg.dtype))
+        out = None
+        if dist is not None:
+            specs = cache_pspecs(cfg, dist, jax.eval_shape(init), self.batch)
+            out = jax.tree.map(lambda s: NamedSharding(dist.mesh, s), specs)
+        self.new_cache = jax.jit(init, out_shardings=out).lower().compile()
         self.prefill, self.step = serve.compile_steps(
-            cfg, None, params, self.new_cache(), self.prompts(-1))
+            cfg, dist, params, self.new_cache(), self.prompts(-1))
 
     def prompts(self, index: int):
         return weights.prompts(self.key, index, self.batch, self.prompt,
@@ -113,9 +121,10 @@ class Server:
                 jax.block_until_ready(self.prefill(
                     self.params, self.new_cache(), prompts))
 
-    def run(self, seconds: float) -> Window:
-        """Rounds back to back for ``seconds``; the last one that started
-        inside the window completes."""
+    def run(self, seconds: float, rounds=None) -> Window:
+        """Rounds back to back for ``seconds``, or until ``rounds`` have
+        completed if that comes first; the last one that started inside
+        the window completes."""
         win = Window(start=time.perf_counter(), end=0.0)
 
         def count(event, *_, **__):
@@ -127,7 +136,8 @@ class Server:
             while True:
                 win.rounds.append(self.round(index))
                 index += 1
-                if win.rounds[-1].end - win.start >= seconds:
+                if (win.rounds[-1].end - win.start >= seconds
+                        or len(win.rounds) == rounds):
                     break
         finally:
             jax.monitoring.unregister_event_duration_listener(count)

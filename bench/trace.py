@@ -66,8 +66,13 @@ def op_name(text: str) -> str:
 
 
 def _events(line, rename=None):
+    names = {}
     for e in line.events:
-        name = rename(e.name) if rename else e.name
+        name = e.name
+        if rename:
+            if name not in names:
+                names[name] = rename(name)
+            name = names[name]
         yield name, float(e.start_ns), float(e.start_ns + e.duration_ns)
 
 
@@ -160,8 +165,11 @@ def module_calls(trace: Trace, device: int, module: str) -> list:
 
 
 def leaves(ops) -> list:
-    """The ops that hold no other op (a ``while`` holds its body's ops)."""
-    ops = sorted(ops, key=lambda o: (o[1], -o[2]))
+    """The ops of some duration that hold no other (a ``while`` holds its
+    body's ops).  An op of no duration holds nothing and is left out: one
+    that starts where a long op starts would make that op read as its
+    body."""
+    ops = sorted((o for o in ops if o[2] > o[1]), key=lambda o: (o[1], -o[2]))
     out = []
     for i, (n, s, e) in enumerate(ops):
         nxt = ops[i + 1] if i + 1 < len(ops) else None

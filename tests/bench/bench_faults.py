@@ -46,9 +46,35 @@ def _half_batch(setattr):
     setattr(serve, "generate", half)
 
 
+def _exchange_left_out(setattr):
+    """The EP exchange between chips is left out: every all-to-all of the
+    dispatch and the combine keeps only the block a chip addresses to
+    itself, so each chip applies its own experts to its own tokens'
+    choices alone, and the choices of other chips' experts add nothing."""
+    import jax.numpy as jnp
+    from jax import lax
+
+    from repro.core import ep
+
+    def own_block(x, axis_name, split_axis, concat_axis, tiled=False):
+        shape = [1] * x.ndim
+        shape[split_axis] = x.shape[split_axis]
+        mine = jnp.arange(x.shape[split_axis]) == lax.axis_index(axis_name)
+        return jnp.where(mine.reshape(shape), x, jnp.zeros_like(x))
+
+    class NoExchange:
+        all_to_all = staticmethod(own_block)
+
+        def __getattr__(self, name):
+            return getattr(lax, name)
+
+    setattr(ep, "lax", NoExchange())
+
+
 FAULTS = {"token_altered": _token_altered,
           "state_unchanged": _state_unchanged,
-          "half_batch": _half_batch}
+          "half_batch": _half_batch,
+          "exchange_left_out": _exchange_left_out}
 
 
 def plant(name: str, setattr=setattr) -> None:

@@ -12,11 +12,12 @@ from pathlib import Path
 import pytest
 
 sys.path.insert(0, str(Path(__file__).resolve().parent))
-import bench_faults  # noqa: E402
 import bench_tiny  # noqa: E402
 from bench import cells, check  # noqa: E402
 
 WORKLOADS = [w["name"] for w in cells.benchmark()["workloads"]]
+MESH_WORKLOADS = [w for w in WORKLOADS
+                  if "mesh" in cells.resolve(w).config]
 
 # Wide enough that float8 rounding moves the logits by more than the
 # limits allow, small enough for the CPU.
@@ -47,9 +48,8 @@ def test_gap_numbers_by_hand():
 @pytest.mark.parametrize("name", WORKLOADS)
 def test_control_run_is_not_correct_where_the_program_is(name, seed):
     runs = {}
-    for control in (False, True):
-        rc, res = bench_tiny.run_tiny(name, seed=seed, seconds=0.2,
-                                      size=CONTROL_SIZE, control=control)
+    for control, (rc, res) in zip((False, True), bench_tiny.runs_tiny(
+            name, (False, True), seed=seed, seconds=0.2, size=CONTROL_SIZE)):
         assert rc == 0
         runs[control] = res
     assert runs[False]["correct"] is True, runs[False]["checks"]
@@ -60,6 +60,13 @@ def test_control_run_is_not_correct_where_the_program_is(name, seed):
                                    "half_batch"])
 @pytest.mark.parametrize("name", WORKLOADS)
 def test_planted_fault_comes_out_not_correct(name, fault, monkeypatch):
-    bench_faults.plant(fault, setattr=monkeypatch.setattr)
-    rc, res = bench_tiny.run_tiny(name)
+    rc, res = bench_tiny.run_tiny(name, fault=fault,
+                                  setattr=monkeypatch.setattr)
+    assert rc == 0 and res["correct"] is False, res["checks"]
+
+
+@pytest.mark.parametrize("name", MESH_WORKLOADS)
+def test_exchange_left_out_comes_out_not_correct(name):
+    # a fault only a cell whose experts are spread over chips can have
+    rc, res = bench_tiny.run_tiny(name, fault="exchange_left_out")
     assert rc == 0 and res["correct"] is False, res["checks"]

@@ -9,6 +9,7 @@ import os
 import re
 import subprocess
 import sys
+import textwrap
 from pathlib import Path
 
 import pytest
@@ -73,8 +74,19 @@ def test_benchmark_file_keeps_its_contract():
         assert m["source"] in ("host_clock", "device_trace")
     for m in SPEC["per_layer"]:
         assert m["moves"] in e2e
-        assert set(m.get("workloads", WORKLOADS)) <= set(WORKLOADS)
+        # every per-layer metric lists the cells it reads something in, so
+        # that a cell added later reports only the metrics that list it
+        assert m["workloads"] and set(m["workloads"]) <= set(WORKLOADS)
         assert (root / "bench" / "metrics" / f"{m['name']}.py").is_file()
+    for w in SPEC["workloads"]:
+        assert any(w["name"] in m["workloads"] for m in SPEC["per_layer"])
+        f = cells.load_json(root / "bench" / "configs" / f"{w['config']}.json")
+        # a configuration on more than one chip names the mesh it runs on
+        if w["chips"] > 1 or "mesh" in f:
+            assert cells.mesh_shape(f, w["chips"]) is not None
+        assert (root / "bench" / f"{f.get('reference', 'reference')}.py"
+                ).is_file()
+        assert callable(cells.reference(f).logits)
     assert sum(w["chips"] == 4 for w in SPEC["workloads"]) <= max(
         1, len(WORKLOADS) // 2)
 
@@ -230,6 +242,29 @@ def test_latency_p95_is_nearest_rank_over_requests():
     assert serve_loop.served_tokens(win, 2, 3) == 120
 
 
+# by hand: program calls a round (the prefill and gen - 1 steps, or prompt +
+# gen - 1 steps on a mesh) x layers x chips, rounds up to 16,000 layer calls
+TRACED_ROUNDS = {"moonlight.decode": -(-16000 // (128 * 2)),
+                 "qwen15moe.decode": -(-16000 // (128 * 2)),
+                 "moonlight.prefill": -(-16000 // (8 * 2)),
+                 "moonlight-ep4.decode": -(-16000 // (255 * 8 * 4))}
+
+
+@pytest.mark.parametrize("name", sorted(TRACED_ROUNDS))
+def test_a_traced_window_ends_by_one_rule(name):
+    from bench import run
+
+    cell = cells.resolve(name)
+    cfg = cells.model_config(cell.config)
+    prefill = cells.mesh_shape(cell.config, cell.chips) is None
+    assert run.traced_rounds(cell.traffic, cfg.n_layers, cell.chips,
+                             prefill) == TRACED_ROUNDS[name]
+    assert TRACED_ROUNDS["moonlight-ep4.decode"] == 2
+    # a 51-s window of the one-chip decode cells serves about 49 rounds
+    assert min(TRACED_ROUNDS[n] for n in TRACED_ROUNDS
+               if cells.resolve(n).chips == 1) > 60
+
+
 def test_sampling_is_fixed_by_the_seed():
     from bench import serve_loop
 
@@ -247,3 +282,134 @@ def test_result_line_is_json_with_checks_last():
     assert list(line)[-1] == "checks"
     assert {"busy_s", "window_s"} <= set(line["device"])
     assert "breakdown" in line
+
+
+MESH_WORKLOADS = [w["name"] for w in SPEC["workloads"]
+                  if "mesh" in cells.resolve(w["name"]).config]
+
+
+@pytest.mark.parametrize("name", MESH_WORKLOADS)
+def test_mesh_cell_serves_the_references_tokens_in_float32(name):
+    # in float32 the EP path and the reference agree token for token
+    rc, res = bench_tiny.run_tiny(name, seed=2**33 + 17)
+    assert rc == 0 and res["correct"] is True, res["checks"]
+    assert res["device"]["count"] == cells.resolve(name).chips
+    # gaps are never negative, so a mean of 0 is every token the best
+    assert res["checks"]["mean_logit_gap"]["value"] == 0
+    assert res["checks"]["failed_requests"]["value"] == 0
+
+
+def test_the_sharded_draw_equals_the_draw_on_one_chip(dist_runner):
+    tiny = {k: bench_tiny.TINY[k] for k in ("model", "moe")}
+    out = dist_runner(textwrap.dedent(f"""
+        import sys
+        sys.path.insert(0, {str(cells.REPO_DIR)!r})
+        import jax, numpy as np
+        from bench import cells, weights
+        from repro.distributed.sharding import make_dist_ctx
+        from repro.launch.mesh import make_bench_mesh
+
+        for name in {MESH_WORKLOADS!r}:
+            cell = cells.resolve(name)
+            cfg = cells.model_config(cell.config, {tiny!r})
+            _, model = cells.mesh_shape(cell.config, cell.chips)
+            dist = make_dist_ctx(cfg, make_bench_mesh(cell.chips, model=model))
+            one = weights.make_params(cfg, 2**40 + 3)
+            many = weights.make_params(cfg, 2**40 + 3, dist)
+            pairs = zip(jax.tree_util.tree_leaves_with_path(one),
+                        jax.tree.leaves(many))
+            for (path, a), b in pairs:
+                assert len(b.sharding.device_set) == cell.chips, path
+                np.testing.assert_array_equal(np.asarray(a), np.asarray(b),
+                                              err_msg=str(path))
+            # each chip holds its share of the experts, not all of them
+            wg = many["blocks"]["slot0"]["moe"]["w_gate"]
+            assert wg.addressable_shards[0].data.shape[1] == \\
+                wg.shape[1] // model
+        print("DRAW-OK")
+    """), n_devices=max(cells.resolve(n).chips for n in MESH_WORKLOADS),
+        timeout=600)
+    assert "DRAW-OK" in out
+
+
+STUB_REFERENCE = '''
+"""A stand-in reference: token 0 is every position's best, and each call
+is recorded with the spread of the weights it was given."""
+import numpy as np
+
+FAN_IN = {"w_down": (-1,)}           # the output width, not the input's
+CALLS = []
+
+
+def logits(config, params, seqs, first, precision="f32"):
+    w = np.asarray(params["blocks"]["slot0"]["moe"]["w_down"])
+    CALLS.append((precision, float(w.std()), w.shape[-1]))
+    seqs = np.asarray(seqs)
+    out = np.zeros((seqs.shape[0], seqs.shape[1] - first,
+                    config["vocab_size"]), np.float32)
+    out[..., 0] = 1.0
+    return out
+'''
+
+
+def test_a_configuration_names_its_reference(tmp_path, monkeypatch):
+    # a configuration added with a reference file of its own needs no edit
+    # to the harness: the check and the weights' fan-in come from its file
+    (tmp_path / "bench").mkdir()
+    (tmp_path / "bench" / "stub_reference.py").write_text(STUB_REFERENCE)
+    monkeypatch.setattr(cells, "REPO_DIR", tmp_path)
+    size = dict(bench_tiny.TINY, config=dict(bench_tiny.TINY["config"],
+                                             reference="stub_reference"))
+    rc, res = bench_tiny.run_tiny(WORKLOADS[0], size=size)
+    stub = cells.reference({"reference": "stub_reference"})
+    assert rc == 0 and res["correct"] is False
+    # served tokens other than 0 lie 1 below the stub's best
+    assert res["checks"]["mean_logit_gap"]["value"] == pytest.approx(
+        res["checks"]["not_first_share"]["value"])
+    assert res["checks"]["not_first_share"]["value"] > 0.5
+    assert [c[0] for c in stub.CALLS] == ["f32"]
+    _, std, width = stub.CALLS[0]
+    assert std == pytest.approx(width ** -0.5, rel=0.05)
+
+
+def test_expert_kernel_roofline_by_hand():
+    from types import SimpleNamespace
+
+    from bench import layers, rooflines
+
+    c = _config("moonlight-8l-ep4")
+    flops, nbytes = rooflines.grouped_swiglu(c, 64, 4)
+    # 64 tokens x top-6 choices arrive at each chip on average, each
+    # through 3 matrices of 2048 x 1408; 16 experts' bf16 weights and the
+    # choices' rows in and out
+    assert flops == 64 * 6 * 3 * 2 * 2048 * 1408 == 6_643_777_536
+    assert (nbytes == 16 * 3 * 2048 * 1408 * 2 + 2 * 64 * 6 * 2048 * 2
+            == 279_969_792)
+    peak = {"bf16_flops_per_s": 197e12, "hbm_bytes_per_s": 819e9}
+    least = rooflines.roofline_s(flops, nbytes, peak)
+    assert least == pytest.approx(279_969_792 / 819e9)     # bound by HBM
+
+    def iv(name, lo, hi):
+        return (name, lo * 1e6, hi * 1e6)                  # ms -> ns
+
+    kernel = "grouped_swiglu_pallas.1 bf16[64,32,2048]"
+    # two chips; chip 0's kernel calls take 0.4, 0.5 and 0.9 ms, chip 1's
+    # 0.6 ms; a kernel call outside the step and other ops do not count
+    dev0 = trace.Device(
+        ops=[iv("while.1", 1, 9), iv(kernel, 1, 1.4), iv("fusion.2", 2, 3),
+             iv(kernel, 3, 3.5), iv(kernel, 11, 11.9), iv(kernel, 20, 29)],
+        modules=[iv("jit_decode_step(3)", 1, 9),
+                 iv("jit_decode_step(3)", 10, 12), iv("jit_other(4)", 20, 30)])
+    dev1 = trace.Device(ops=[iv(kernel, 1, 1.6)],
+                        modules=[iv("jit_decode_step(3)", 1, 2)])
+    tr = layers.ScopedTrace(devices={0: dev0, 1: dev1},
+                            spans=[iv("bench.round", 0, 40)])
+    assert layers.METRICS["expert_kernel_ms"](tr) == pytest.approx(0.55)
+    ctx = SimpleNamespace(trace=tr, config=c, chips=4, peak=peak,
+                          traffic={"batch": 64})
+    got = cells.metric_reader("expert_kernel_roofline").read(ctx)
+    assert got == pytest.approx(100 * least / 0.55e-3)
+    empty = layers.ScopedTrace(devices={0: trace.Device()},
+                               spans=[iv("bench.round", 0, 1)])
+    assert cells.metric_reader("expert_kernel_roofline").read(
+        SimpleNamespace(trace=empty)) is None

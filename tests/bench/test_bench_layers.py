@@ -199,7 +199,7 @@ def test_an_op_of_no_duration_holds_nothing():
     # of a 15.6-ms fusion of the prefill
     ops = [("while", 0.0, 100.0), ("fusion", 10.0, 25.0), ("cc", 10.0, 10.0),
            ("copy", 30.0, 31.0)]
-    assert [n for n, _, _ in layers.leaves(ops)] == ["fusion", "copy"]
+    assert [n for n, _, _ in trace.leaves(ops)] == ["fusion", "copy"]
 
 
 def test_breakdown_names_a_gap_by_the_innermost_program_span():
